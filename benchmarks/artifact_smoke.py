@@ -6,7 +6,9 @@ fingerprint while the serve stats are non-empty.
 This is the CI ``artifact-smoke`` job — the export -> load -> serve
 separation the artifact layer exists for: the expensive prune/tune
 session lives and dies in process one; process two restarts the serve
-path from disk alone.
+path from disk alone. It is a CPU correctness check: the child runs with
+``JAX_PLATFORMS=cpu`` whatever the parent's backend, so a parent that
+holds a chip never shares it with its child.
 """
 from __future__ import annotations
 
@@ -54,6 +56,7 @@ def _child_env() -> dict:
     src = os.path.dirname(os.path.abspath(list(repro.__path__)[0]))
     env = dict(os.environ)
     env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
+    env["JAX_PLATFORMS"] = "cpu"
     return env
 
 

@@ -3,13 +3,13 @@ bit-identity plus the replica fleet balancer.
 
 Three arms:
 
-  * ``dist_tp2_identity`` — a subprocess under
-    ``XLA_FLAGS=--xla_force_host_platform_device_count=4`` greedy-decodes
-    the same reduced model once on a single device and once sharded tp=2
-    over a ``(1, 2)`` (data, model) mesh, for both KV layouts. The token
-    streams must be **bit-identical**: GSPMD partitions the very jaxpr
-    the single-device engine traced, so sharding is an execution detail,
-    never a math change.
+  * ``dist_tp2_identity`` — a CPU subprocess (``JAX_PLATFORMS=cpu``)
+    under ``XLA_FLAGS=--xla_force_host_platform_device_count=4``
+    greedy-decodes the same reduced model once on a single device and
+    once sharded tp=2 over a ``(1, 2)`` (data, model) mesh, for both KV
+    layouts. The token streams must be **bit-identical**: GSPMD
+    partitions the very jaxpr the single-device engine traced, so
+    sharding is an execution detail, never a math change.
   * ``dist_fleet_vs_solo`` — a 2-replica :class:`ReplicaSet` draining the
     interleaved serve workload vs one replica alone, both warmed.
     Least-loaded outstanding-token dispatch must sustain >= the single
@@ -70,7 +70,9 @@ def _workload(cfg):
 
 # The parent process already initialised jax with however many devices the
 # host has, and XLA_FLAGS is read once at import — so the tp=2 arm runs in
-# a fresh interpreter where the flag can still take effect.
+# a fresh interpreter where the flag can still take effect. It is a CPU
+# correctness check on four virtual host devices: the child runs with
+# JAX_PLATFORMS=cpu, so it never asks for a chip the parent may hold.
 _TP2_CODE = textwrap.dedent("""
     import jax, numpy as np
     from benchmarks import common
@@ -119,6 +121,7 @@ def run_tp2_identity():
     t = common.Timer()
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = "src:."
     proc = subprocess.run([sys.executable, "-c", _TP2_CODE],
                           capture_output=True, text=True, timeout=900,

@@ -7,11 +7,11 @@ padding/clipping math) as a :class:`KernelCall` and validates it against
 the active/passed :class:`~repro.api.targets.TargetSpec`:
 
 ``K001 tile-not-divisible``
-    a chosen tile is not a multiple of the hardware extent it maps onto
-    (``bm``/``bq``/``bs`` second-minor tiles -> SUBLANE, ``bk``/``bn``/
-    ``bw`` minor tiles -> LANE). A tile covering the whole (padded) dim
-    is exempt — the kernel pads the operand itself and the grid has one
-    step over that dim.
+    a block breaks the TPU lowering's tiling rule: each of its last two
+    dims must be a multiple of the hardware extent it maps onto
+    (second-minor -> SUBLANE, minor -> LANE) or equal the operand's own
+    dim there. This is the rule Mosaic enforces when it compiles a
+    ``BlockSpec``, so a K001-clean launch is not refused for its blocks.
 ``K002 grid-bounds``
     a grid dimension <= 0, or a total step count past int32.
 ``K003 vmem-overflow``
@@ -52,14 +52,15 @@ def _ceil_to(x: int, b: int) -> int:
 class KernelCall:
     """One Pallas launch, statically described.
 
-    ``tiles`` maps a tile name to ``(tile, padded_dim, hw_extent)`` —
-    the K001 inputs; ``vmem_bytes`` is the double-buffered footprint.
+    ``blocks`` maps an operand name to ``(block_shape, array_shape)`` —
+    each ``BlockSpec`` of the launch over the (padded) array it tiles, the
+    K001 inputs; ``vmem_bytes`` is the double-buffered footprint.
     """
 
     kernel: str                    # matmul | moe_gmm | flash_attention | ...
     site: str                      # human label ("stack/pos0:ffn up" etc.)
     grid: Tuple[int, ...]
-    tiles: Dict[str, Tuple[int, int, int]]
+    blocks: Dict[str, Tuple[Tuple[int, ...], Tuple[int, ...]]]
     vmem_bytes: int
     dtype_bytes: int
     is_gemm: bool = True
@@ -68,30 +69,32 @@ class KernelCall:
 # -- per-kernel describers (mirror each kernel's launch math) ---------------
 
 def describe_matmul(m: int, k: int, n: int, block: Block, *,
-                    dtype_bytes: int = 2, site: str = "matmul",
-                    lane: int = 128, sublane: int = 8) -> KernelCall:
+                    dtype_bytes: int = 2, site: str = "matmul"
+                    ) -> KernelCall:
     bm, bk, bn = block.bm, block.bk, block.bn
     mp, kp, np_ = _ceil_to(m, bm), _ceil_to(k, bk), _ceil_to(n, bn)
     return KernelCall(
         kernel="matmul", site=site,
         grid=(mp // bm, np_ // bn, kp // bk),
-        tiles={"bm": (bm, mp, sublane), "bk": (bk, kp, lane),
-               "bn": (bn, np_, lane)},
+        blocks={"a": ((bm, bk), (mp, kp)), "b": ((bk, bn), (kp, np_)),
+                "out": ((bm, bn), (mp, np_))},
         vmem_bytes=cost_model.block_vmem_bytes(bm, bk, bn, dtype_bytes),
         dtype_bytes=dtype_bytes)
 
 
 def describe_moe_gmm(n_experts: int, c: int, k: int, n: int, block: Block, *,
-                     dtype_bytes: int = 2, site: str = "moe_gmm",
-                     lane: int = 128, sublane: int = 8) -> KernelCall:
+                     dtype_bytes: int = 2, site: str = "moe_gmm"
+                     ) -> KernelCall:
     # the kernel clips the block to the operand dims before padding
     bm, bk, bn = min(block.bm, c), min(block.bk, k), min(block.bn, n)
     cp, kp, np_ = _ceil_to(c, bm), _ceil_to(k, bk), _ceil_to(n, bn)
+    e = n_experts
     return KernelCall(
         kernel="moe_gmm", site=site,
-        grid=(n_experts, cp // bm, np_ // bn, kp // bk),
-        tiles={"bm": (bm, cp, sublane), "bk": (bk, kp, lane),
-               "bn": (bn, np_, lane)},
+        grid=(e, cp // bm, np_ // bn, kp // bk),
+        blocks={"x": ((1, bm, bk), (e, cp, kp)),
+                "w": ((1, bk, bn), (e, kp, np_)),
+                "out": ((1, bm, bn), (e, cp, np_))},
         vmem_bytes=cost_model.block_vmem_bytes(bm, bk, bn, dtype_bytes),
         dtype_bytes=dtype_bytes)
 
@@ -99,60 +102,65 @@ def describe_moe_gmm(n_experts: int, c: int, k: int, n: int, block: Block, *,
 def describe_flash_attention(batch: int, sq: int, sk: int, n_heads: int,
                              head_dim: int, *, bq: int = 128, bk: int = 128,
                              dtype_bytes: int = 2,
-                             site: str = "flash_attention",
-                             lane: int = 128, sublane: int = 8) -> KernelCall:
+                             site: str = "flash_attention") -> KernelCall:
     bq = min(bq, max(sq, 8))
     bk = min(bk, max(sk, 8))
     sqp, skp = _ceil_to(max(sq, 8), bq), _ceil_to(max(sk, 8), bk)
-    d = head_dim
+    d, bh = head_dim, batch * n_heads
     # q/k/v blocks double-buffered + f32 online-softmax scratch
     # ((bq,128) running max + (bq,128) running sum + (bq,D) accumulator)
     vmem = (2 * dtype_bytes * (bq * d + 2 * bk * d)
             + 4 * (2 * bq * 128 + bq * d))
     return KernelCall(
         kernel="flash_attention", site=site,
-        grid=(batch * n_heads, sqp // bq, skp // bk),
-        tiles={"bq": (bq, sqp, sublane), "bk": (bk, skp, sublane)},
+        grid=(bh, sqp // bq, skp // bk),
+        blocks={"q": ((1, bq, d), (bh, sqp, d)),
+                "kv": ((1, bk, d), (bh, skp, d)),
+                "out": ((1, bq, d), (bh, sqp, d))},
         vmem_bytes=vmem, dtype_bytes=dtype_bytes, is_gemm=False)
 
 
 def describe_paged_attention(batch: int, n_heads: int, head_dim: int,
                              n_cols: int, page_size: int, *,
-                             dtype_bytes: int = 2,
-                             site: str = "paged_attention",
-                             lane: int = 128, sublane: int = 8) -> KernelCall:
-    d, bs = head_dim, page_size
-    # q (1,1,D) + one KV block (1,bs,1,D) each way, double-buffered;
-    # f32 scratch (1,128)x2 + (1,D)
-    vmem = 2 * dtype_bytes * (d + 2 * bs * d) + 4 * (2 * 128 + d)
+                             n_kv_heads: int, dtype_bytes: int = 2,
+                             site: str = "paged_attention") -> KernelCall:
+    d, bs, hq, hkv = head_dim, page_size, n_heads, n_kv_heads
+    n_blocks = RESERVED_BLOCKS + batch * n_cols
+    # q (1,Hq,D) + one KV block (1,bs,Hkv,D) each way, double-buffered;
+    # f32 scratch (Hq,128)x2 + (Hq,D)
+    vmem = (2 * dtype_bytes * (hq * d + 2 * bs * hkv * d)
+            + 4 * (2 * hq * 128 + hq * d))
     return KernelCall(
         kernel="paged_attention", site=site,
-        grid=(batch, n_heads, n_cols),
-        tiles={"bs": (bs, n_cols * bs, sublane)},
+        grid=(batch, n_cols),
+        blocks={"q": ((1, hq, d), (batch, hq, d)),
+                "kv": ((1, bs, hkv, d), (n_blocks, bs, hkv, d)),
+                "out": ((1, hq, d), (batch, hq, d))},
         vmem_bytes=vmem, dtype_bytes=dtype_bytes, is_gemm=False)
 
 
 def describe_rwkv6_scan(batch: int, seq: int, n_heads: int, head_dim: int, *,
                         bs: int = 64, dtype_bytes: int = 2,
-                        site: str = "rwkv6_scan",
-                        lane: int = 128, sublane: int = 8) -> KernelCall:
+                        site: str = "rwkv6_scan") -> KernelCall:
     bs = min(bs, seq)
     sp = _ceil_to(seq, bs)
-    d = head_dim
+    d, bh = head_dim, batch * n_heads
     # r/k/v/w blocks + the (D,) bonus row, double-buffered; f32 state
     # scratch (D,D) + carried state block (D,D)
     vmem = (2 * dtype_bytes * (4 * bs * d + d) + 4 * 2 * d * d)
     return KernelCall(
         kernel="rwkv6_scan", site=site,
-        grid=(batch * n_heads, sp // bs),
-        tiles={"bs": (bs, sp, sublane)},
+        grid=(bh, sp // bs),
+        blocks={"rkvw": ((1, bs, d), (bh, sp, d)),
+                "u": ((1, 1, d), (n_heads, 1, d)),
+                "out": ((1, bs, d), (bh, sp, d)),
+                "state": ((1, d, d), (bh, d, d))},
         vmem_bytes=vmem, dtype_bytes=dtype_bytes, is_gemm=False)
 
 
 def describe_rglru_scan(batch: int, seq: int, width: int, *, bs: int = 128,
                         bw: int = 128, dtype_bytes: int = 2,
-                        site: str = "rglru_scan",
-                        lane: int = 128, sublane: int = 8) -> KernelCall:
+                        site: str = "rglru_scan") -> KernelCall:
     bs, bw = min(bs, seq), min(bw, width)
     sp, wp = _ceil_to(seq, bs), _ceil_to(width, bw)
     # a/x blocks double-buffered + f32 carry scratch (1,bw)
@@ -160,7 +168,8 @@ def describe_rglru_scan(batch: int, seq: int, width: int, *, bs: int = 128,
     return KernelCall(
         kernel="rglru_scan", site=site,
         grid=(batch, wp // bw, sp // bs),
-        tiles={"bs": (bs, sp, sublane), "bw": (bw, wp, lane)},
+        blocks={"ax": ((1, bs, bw), (batch, sp, wp)),
+                "out": ((1, bs, bw), (batch, sp, wp))},
         vmem_bytes=vmem, dtype_bytes=dtype_bytes, is_gemm=False)
 
 
@@ -168,17 +177,21 @@ def describe_rglru_scan(batch: int, seq: int, width: int, *, bs: int = 128,
 
 def check_call(call: KernelCall, target) -> List[Diagnostic]:
     """Validate one described launch against ``target`` (anything with
-    ``vmem_bytes``; lane/sublane are carried in the call's tiles)."""
+    ``vmem_bytes``; ``lane``/``sublane`` default to the cost model's)."""
     out: List[Diagnostic] = []
     where = f"{call.kernel}[{call.site}]"
-    for name, (tile, dim, hw) in call.tiles.items():
-        if tile < dim and tile % hw:
-            out.append(Diagnostic(
-                "K001", ERROR, where,
-                f"{name}={tile} tiles a dim of {dim} but is not a "
-                f"multiple of the hardware extent {hw}",
-                fix_hint=f"round {name} to a multiple of {hw} (or cover "
-                         f"the whole dim)"))
+    lane, sublane = _target_geom(target)
+    for name, (block, array) in call.blocks.items():
+        for axis, hw in ((-2, sublane), (-1, lane)):
+            b, a = block[axis], array[axis]
+            if b % hw and b != a:
+                out.append(Diagnostic(
+                    "K001", ERROR, where,
+                    f"{name} block {block} over array {array}: dim "
+                    f"{axis} is {b}, neither a multiple of {hw} nor the "
+                    f"array's {a}",
+                    fix_hint=f"make that block dim a multiple of {hw} or "
+                             f"the whole array dim"))
     if any(g <= 0 for g in call.grid):
         out.append(Diagnostic(
             "K002", ERROR, where,
@@ -222,7 +235,6 @@ def _target_geom(target) -> Tuple[int, int]:
 
 def check_table_kernels(table: TaskTable, target) -> List[Diagnostic]:
     """K-checks for every tuned GEMM program in a task table."""
-    lane, sublane = _target_geom(target)
     out: List[Diagnostic] = []
     for task in table.tasks:
         site = task.sites[0]
@@ -231,13 +243,11 @@ def check_table_kernels(table: TaskTable, target) -> List[Diagnostic]:
             if site.kind in ("moe_ffn",) and prog.batch > 1:
                 call = describe_moe_gmm(
                     prog.batch, prog.m, prog.k, prog.n, prog.block,
-                    dtype_bytes=prog.dtype_bytes, site=label,
-                    lane=lane, sublane=sublane)
+                    dtype_bytes=prog.dtype_bytes, site=label)
             else:
                 call = describe_matmul(
                     prog.m, prog.k, prog.n, prog.block,
-                    dtype_bytes=prog.dtype_bytes, site=label,
-                    lane=lane, sublane=sublane)
+                    dtype_bytes=prog.dtype_bytes, site=label)
             out.extend(check_call(call, target))
     return out
 
@@ -259,7 +269,6 @@ def check_model_kernels(cfg, target, *, table: Optional[TaskTable] = None,
     the serve shapes.
     """
     from repro.models.model import prune_sites
-    lane, sublane = _target_geom(target)
     db = 2 if cfg.dtype == "bfloat16" else 4
     out: List[Diagnostic] = []
 
@@ -285,22 +294,21 @@ def check_model_kernels(cfg, target, *, table: Optional[TaskTable] = None,
     if kinds & {ATTN, LOCAL_ATTN}:
         out.extend(check_call(describe_flash_attention(
             max_batch, max_seq, max_seq, cfg.n_heads, cfg.head_dim,
-            dtype_bytes=db, site=f"{cfg.name} prefill", lane=lane,
-            sublane=sublane), target))
+            dtype_bytes=db, site=f"{cfg.name} prefill"), target))
         n_cols = -(-max_seq // page_size)
         out.extend(check_call(describe_paged_attention(
             max_batch, cfg.n_heads, cfg.head_dim, n_cols, page_size,
-            dtype_bytes=db, site=f"{cfg.name} paged decode", lane=lane,
-            sublane=sublane), target))
+            n_kv_heads=cfg.n_kv_heads, dtype_bytes=db,
+            site=f"{cfg.name} paged decode"), target))
     if RWKV in kinds:
         out.extend(check_call(describe_rwkv6_scan(
             max_batch, max_seq, max(1, cfg.d_model // cfg.rwkv_head_dim),
             cfg.rwkv_head_dim, dtype_bytes=db,
-            site=f"{cfg.name} rwkv6", lane=lane, sublane=sublane), target))
+            site=f"{cfg.name} rwkv6"), target))
     if RGLRU in kinds:
         out.extend(check_call(describe_rglru_scan(
             max_batch, max_seq, cfg.rglru_width, dtype_bytes=db,
-            site=f"{cfg.name} rglru", lane=lane, sublane=sublane), target))
+            site=f"{cfg.name} rglru"), target))
     return out
 
 

@@ -157,7 +157,9 @@ class MeasurementConfig:
     trim: int = 1            # drop this many fastest+slowest before median
     measure_top_k: int = 4   # analytic-shortlisted candidates actually built
     max_grid_steps: int = 2  # grid steps measured per dim (then extrapolated)
-    interpret: Optional[bool] = None   # None = interpret unless on a TPU
+    # None = interpret unless on a TPU; True on a TPU raises (the
+    # measured backend never times the interpreter on the chip)
+    interpret: Optional[bool] = None
 
     def fingerprint(self) -> Tuple:
         return (self.warmup, self.repeats, self.trim, self.measure_top_k,
@@ -424,11 +426,12 @@ _RECORDING_IDS = itertools.count(1)
 class MeasuredOracle(_MeasurementOracle):
     """Times the repo's own Pallas kernels for every shortlisted candidate.
 
-    On this CPU container the kernels run with ``interpret=True`` (the
-    same code path a TPU compiles); on a TPU backend they are real
-    compiled timings. Pass ``record=MeasurementLog()`` to capture every
-    measurement for later :class:`ReplayOracle` playback — the log also
-    memoizes within the run, so a problem is never timed twice.
+    Off the TPU the kernels run with ``interpret=True`` (the same code
+    path a TPU compiles); on a TPU backend they are real compiled
+    timings, and forcing interpret mode there raises. Pass
+    ``record=MeasurementLog()`` to capture every measurement for later
+    :class:`ReplayOracle` playback — the log also memoizes within the
+    run, so a problem is never timed twice.
     """
 
     name = "measured"
@@ -451,10 +454,8 @@ class MeasuredOracle(_MeasurementOracle):
         return fp
 
     def _interpret(self) -> bool:
-        if self.config.interpret is not None:
-            return self.config.interpret
-        import jax
-        return jax.default_backend() != "tpu"
+        from repro.kernels.ops import interpret_mode
+        return interpret_mode(self.config.interpret)
 
     def _clipped(self, m, k, n, batch, block):
         """Measured problem dims: at most ``max_grid_steps`` grid steps per
@@ -547,8 +548,8 @@ class MeasuredOracle(_MeasurementOracle):
     def paged_attention_cost(self, batch, kv_len, n_heads, head_dim, *,
                              n_kv_heads=1, block_size=16,
                              dtype_bytes=2) -> float:
-        """Times the real paged-decode kernel (the grid is (batch, heads,
-        kv chunks) — clip chunks and batch, extrapolate by the exact
+        """Times the real paged-decode kernel (the grid is (batch, kv
+        chunks) — clip chunks and batch, extrapolate by the exact
         step-count ratio, exactly like :meth:`_clipped` for GEMMs).
         Memoized under the full-dimension key in ``record``, so a replay
         of this run reproduces the same step predictions."""

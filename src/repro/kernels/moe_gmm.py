@@ -17,7 +17,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.core.cost_model import Block
-from repro.kernels.matmul import vmem
+from repro.kernels.matmul import vmem, vmem_limit
 
 
 def _gmm_kernel(x_ref, w_ref, o_ref, acc_ref, *, n_k: int):
@@ -56,6 +56,7 @@ def moe_gmm(x: jax.Array, w: jax.Array, *, block: Block = Block(128, 256, 256),
         out_specs=pl.BlockSpec((1, bm, bn), lambda e, i, j, k: (e, i, j)),
         out_shape=jax.ShapeDtypeStruct((E, C + pc, N + pn), x.dtype),
         scratch_shapes=[vmem((bm, bn), jnp.float32)],
+        compiler_params=vmem_limit(),
         interpret=interpret,
     )(x, w)
     return out[:, :C, :N]

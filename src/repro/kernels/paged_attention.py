@@ -1,13 +1,22 @@
 """Paged decode attention Pallas kernel: single-token queries reading K/V
 through a block table (vLLM-style), online-softmax.
 
-Grid: (B, Hq, n_cols) with the block-table column minor. The table and the
+Grid: (B, n_cols) with the block-table column minor. The table and the
 per-row sequence lengths ride in as scalar-prefetch operands
 (``PrefetchScalarGridSpec``) so the KV BlockSpec index map can chase the
-indirection — grid step (b, h, ki) DMAs pool block ``table[b, ki]`` for KV
-head ``h // (Hq // Hkv)``; the pool itself never moves. Running max / sum /
+indirection — grid step (b, ki) DMAs pool block ``table[b, ki]`` for every
+KV head at once; the pool itself never moves. Running max / sum /
 accumulator live in VMEM scratch across column steps, exactly the
 ``flash_attention`` schedule with the KV walk order given by the table.
+
+Every block spans the full trailing two dims of its array — q ``(1, Hq,
+D)``, K/V ``(1, bs, Hkv, D)`` — which is what the TPU lowering requires of
+a block whose head count is not a multiple of 8. Inside the body the
+block's ``(bs, Hkv)`` rows flatten to ``bs * Hkv`` keys (row ``t * Hkv +
+h``) and one matmul scores every q head against all of them; a key of
+another KV head than the q head's group (``h != q_head // (Hq // Hkv)``)
+is masked like a future position. That spends ``Hkv`` times the MXU work
+of a per-head walk on masked keys.
 
 Numerics match ``blockwise_attention`` / ``ref.paged_attention_ref`` (the
 oracle); positions are implicit — slot (c, o) holds absolute position
@@ -17,7 +26,8 @@ c * block_size + o, so masking ``c*bs + o >= seq_len`` is the causal mask.
 its own freshly written position; an all-masked *first* column would poison
 the running max).
 
-Validated with interpret=True against ref.paged_attention_ref.
+Validated with interpret=True against ref.paged_attention_ref, and compiled
+for TPU v5e by ``tests/test_tpu_compile.py``.
 """
 from __future__ import annotations
 
@@ -35,9 +45,10 @@ NEG_INF = -1e30
 
 
 def _pa_kernel(tbl_ref, lens_ref, q_ref, k_ref, v_ref, o_ref,
-               m_ref, l_ref, acc_ref, *, scale: float, bs: int, n_c: int):
+               m_ref, l_ref, acc_ref, *, scale: float, bs: int, n_kv: int,
+               group: int, n_c: int):
     b = pl.program_id(0)
-    ki = pl.program_id(2)
+    ki = pl.program_id(1)
 
     @pl.when(ki == 0)
     def _init():
@@ -45,17 +56,22 @@ def _pa_kernel(tbl_ref, lens_ref, q_ref, k_ref, v_ref, o_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[0].astype(jnp.float32) * scale          # (1, D)
-    k = k_ref[0, :, 0, :].astype(jnp.float32)         # (bs, D)
-    v = v_ref[0, :, 0, :].astype(jnp.float32)
-    s = jnp.dot(q, k.T, preferred_element_type=jnp.float32)  # (1, bs)
+    q = q_ref[0].astype(jnp.float32) * scale                  # (Hq, D)
+    n_q, d = q.shape
+    k = k_ref[0].astype(jnp.float32).reshape(bs * n_kv, d)    # (bs*Hkv, D)
+    v = v_ref[0].astype(jnp.float32).reshape(bs * n_kv, d)
+    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32)
 
-    # slot o of column ki holds absolute position ki*bs + o; everything at
+    # key row t*Hkv + h is position ki*bs + t of KV head h; everything at
     # or past seq_len is unwritten (zero block, pad garbage, future slots)
-    offs = ki * bs + jax.lax.broadcasted_iota(jnp.int32, (1, bs), 1)
-    s = jnp.where(offs < lens_ref[b], s, NEG_INF)
+    row = jax.lax.broadcasted_iota(jnp.int32, (n_q, bs * n_kv), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (n_q, bs * n_kv), 1)
+    live = ((col % n_kv == row // group)
+            & (ki * bs + col // n_kv < lens_ref[b]))
+    s = jnp.where(live, s, NEG_INF)
 
-    m_prev = m_ref[...][:, :1]                        # (1, 1)
+    m_prev = m_ref[...][:, :1]                                # (Hq, 1)
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
     p = jnp.exp(s - m_new)
     corr = jnp.exp(m_prev - m_new)
@@ -80,30 +96,29 @@ def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
     Returns (B, Hq, D)."""
     B, Hq, D = q.shape
     _, bs, Hkv, _ = k_pool.shape
-    g = Hq // Hkv
     n_c = block_table.shape[1]
     scale = scale if scale is not None else D ** -0.5
-    grid = (B, Hq, n_c)
 
+    kv_spec = pl.BlockSpec((1, bs, Hkv, D),
+                           lambda b, ki, tbl, lens: (tbl[b, ki], 0, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=grid,
+        grid=(B, n_c),
         in_specs=[
-            pl.BlockSpec((1, 1, D), lambda b, h, ki, tbl, lens: (b, h, 0)),
-            pl.BlockSpec((1, bs, 1, D),
-                         lambda b, h, ki, tbl, lens: (tbl[b, ki], 0, h // g, 0)),
-            pl.BlockSpec((1, bs, 1, D),
-                         lambda b, h, ki, tbl, lens: (tbl[b, ki], 0, h // g, 0)),
+            pl.BlockSpec((1, Hq, D), lambda b, ki, tbl, lens: (b, 0, 0)),
+            kv_spec,
+            kv_spec,
         ],
-        out_specs=pl.BlockSpec((1, 1, D), lambda b, h, ki, tbl, lens: (b, h, 0)),
+        out_specs=pl.BlockSpec((1, Hq, D), lambda b, ki, tbl, lens: (b, 0, 0)),
         scratch_shapes=[
-            vmem((1, 128), jnp.float32),   # running max (lane-replicated)
-            vmem((1, 128), jnp.float32),   # running sum
-            vmem((1, D), jnp.float32),     # output accumulator
+            vmem((Hq, 128), jnp.float32),   # running max (lane-replicated)
+            vmem((Hq, 128), jnp.float32),   # running sum
+            vmem((Hq, D), jnp.float32),     # output accumulator
         ],
     )
     return pl.pallas_call(
-        functools.partial(_pa_kernel, scale=scale, bs=bs, n_c=n_c),
+        functools.partial(_pa_kernel, scale=scale, bs=bs, n_kv=Hkv,
+                          group=Hq // Hkv, n_c=n_c),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hq, D), q.dtype),
         interpret=interpret,

@@ -32,7 +32,7 @@ def _wkv_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, o_ref, s_out_ref, s_ref,
     k = k_ref[0].astype(jnp.float32)
     v = v_ref[0].astype(jnp.float32)
     w = w_ref[0].astype(jnp.float32)
-    u = u_ref[0].astype(jnp.float32)   # (D,) for this head
+    u = u_ref[0, 0].astype(jnp.float32)   # (D,) for this head
 
     def body(t, carry):
         s, out = carry                  # s: (D, D)
@@ -77,7 +77,7 @@ def rwkv6_scan(r, k, v, w, u, *, bs: int = 64, interpret: bool = False):
             pl.BlockSpec((1, bs, D), lambda bh, s: (bh, s, 0)),
             pl.BlockSpec((1, bs, D), lambda bh, s: (bh, s, 0)),
             pl.BlockSpec((1, bs, D), lambda bh, s: (bh, s, 0)),
-            pl.BlockSpec((1, D), lambda bh, s: (bh % H, 0)),
+            pl.BlockSpec((1, 1, D), lambda bh, s: (bh % H, 0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, bs, D), lambda bh, s: (bh, s, 0)),
@@ -89,6 +89,6 @@ def rwkv6_scan(r, k, v, w, u, *, bs: int = 64, interpret: bool = False):
         ],
         scratch_shapes=[vmem((D, D), jnp.float32)],
         interpret=interpret,
-    )(rt, kt, vt, wt, u)
+    )(rt, kt, vt, wt, u.reshape(H, 1, D))
     o = o[:, :S].reshape(B, H, S, D).transpose(0, 2, 1, 3)
     return o, s_last.reshape(B, H, D, D)

@@ -16,15 +16,11 @@ class MeshError(ValueError):
     """Requested mesh shape does not fit the available devices."""
 
 
-def make_mesh_compat(shape, axes, devices=None):
-    """``jax.make_mesh`` across jax versions: newer jax takes ``axis_types``
-    (we want Auto, its default); jax <= 0.4 has neither the kwarg nor
-    ``jax.sharding.AxisType`` — omitting them is the same behavior."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes, devices=devices)
+def make_mesh(shape, axes, devices=None):
+    """``jax.make_mesh`` with every axis ``Auto`` (GSPMD propagates the
+    shardings) — jax's own default is ``Explicit``."""
     return jax.make_mesh(shape, axes, devices=devices,
-                         axis_types=(axis_type.Auto,) * len(shape))
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(shape))
 
 
 def _check_devices(shape: Tuple[int, ...], axes: Tuple[str, ...],
@@ -51,7 +47,7 @@ def make_production_mesh(*, multi_pod: bool = False):
     _check_devices(shape, axes,
                    f"make_production_mesh(multi_pod={multi_pod})")
     n = math.prod(shape)
-    return make_mesh_compat(shape, axes, jax.devices()[:n])
+    return make_mesh(shape, axes, jax.devices()[:n])
 
 
 def make_test_mesh(n_devices: Optional[int] = None, *,
@@ -70,8 +66,8 @@ def make_test_mesh(n_devices: Optional[int] = None, *,
     data = n // model
     _check_devices((data, model), ("data", "model"),
                    f"make_test_mesh(n_devices={n}, model={model})")
-    return make_mesh_compat((data, model), ("data", "model"),
-                            jax.devices()[: data * model])
+    return make_mesh((data, model), ("data", "model"),
+                     jax.devices()[: data * model])
 
 
 def required_devices(multi_pod: bool) -> int:

@@ -172,7 +172,9 @@ def _serving_mesh(args):
     return mesh
 
 
-def _requests(args, cfg, budgets):
+def synthetic_requests(args, cfg, budgets):
+    """The launcher's seeded traffic: ``args.requests`` random prompts of
+    ``args.prompt_len`` tokens, alternating greedy and temperature 0.8."""
     import numpy as np
     rng = np.random.default_rng(0)
     from repro.serve.engine import Request
@@ -251,8 +253,11 @@ def main():
 
     from repro.configs import get_config, get_reduced_config
     from repro.core.oracle import MeasurementLog
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.models.model import init_params
     from repro.serve.engine import ServeEngine
+
+    enable_compile_cache()
 
     log = MeasurementLog() if args.record else None
     budgets = [float(b) * 1e-3 for b in args.budget_ms.split(",")] \
@@ -303,7 +308,7 @@ def main():
                   f"probation={acfg.probation_steps} "
                   f"keep={acfg.keep_generations} generations")
         shed = 0
-        for req in _requests(args, cfg, budgets):
+        for req in synthetic_requests(args, cfg, budgets):
             try:
                 router.submit(req)
             except RouteError as e:
@@ -348,7 +353,7 @@ def main():
                                   if mesh is not None else {})))
         print(f"supervising {args.replicas} replica(s) of {args.artifact} "
               f"(model={cfg.name}, chaos={'on' if args.chaos else 'off'})")
-        for req in _requests(args, cfg, budgets):
+        for req in synthetic_requests(args, cfg, budgets):
             sup.submit(req)
         _print_stats(sup.run())
         if log is not None:
@@ -378,7 +383,7 @@ def main():
             eng = ServeEngine(cfg, params, max_batch=min(8, args.requests),
                               max_seq=args.prompt_len + args.max_new,
                               scheduler=args.scheduler, measurements=log)
-    for req in _requests(args, cfg, budgets):
+    for req in synthetic_requests(args, cfg, budgets):
         eng.submit(req)
     stats = eng.run()
     _print_stats(stats)

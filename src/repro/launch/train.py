@@ -54,9 +54,9 @@ def main():
     from repro.train.checkpoint import CheckpointManager
 
     cfg = (get_reduced_config if args.reduced else get_config)(args.arch)
-    from repro.launch.mesh import make_mesh_compat
-    mesh = make_mesh_compat((args.dp, args.tp), ("data", "model"),
-                            jax.devices()[: args.dp * args.tp])
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((args.dp, args.tp), ("data", "model"),
+                     jax.devices()[: args.dp * args.tp])
     model = steps_mod.build_model(cfg, mesh)
     pipe = DataPipeline(cfg, global_batch=args.batch, seq_len=args.seq)
 

@@ -269,8 +269,9 @@ def attention_prefill(params: dict, x: jax.Array, cfg, *, positions,
 
 def _use_paged_kernel() -> bool:
     """Pallas on TPU, XLA gather+``direct_attention`` elsewhere (the same
-    math; Pallas does not lower to the CPU backend). Overridable with
-    REPRO_PAGED_BACKEND=pallas|xla for kernel testing."""
+    math). Overridable with REPRO_PAGED_BACKEND=pallas|xla for kernel
+    testing: forced ``pallas`` off the TPU runs the kernel in interpret
+    mode (see :func:`repro.kernels.ops.interpret_mode`)."""
     import os
     forced = os.environ.get("REPRO_PAGED_BACKEND", "auto")
     if forced == "pallas":
@@ -278,6 +279,33 @@ def _use_paged_kernel() -> bool:
     if forced == "xla":
         return False
     return jax.default_backend() == "tpu"
+
+
+def _paged_kernel(n_kv: int):
+    """The Pallas paged-decode kernel, fitted to the mesh in context.
+
+    A Mosaic kernel cannot be partitioned by GSPMD, so under a mesh with
+    a ``model`` axis (``jax.set_mesh``, as ``ShardedServeEngine`` traces
+    its decode step) the kernel runs per shard through ``shard_map``:
+    each device attends its own KV heads and the q heads of their groups,
+    the axis the pools are sharded on. Heads the axis does not divide
+    stay replicated, as the pools do."""
+    from functools import partial
+
+    from jax.sharding import PartitionSpec as P
+
+    from repro.kernels.ops import interpret_mode
+    from repro.kernels.paged_attention import paged_attention
+    kernel = partial(paged_attention, interpret=interpret_mode())
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or mesh.shape.get("model", 1) == 1:
+        return kernel
+    heads = "model" if n_kv % mesh.shape["model"] == 0 else None
+    return jax.shard_map(
+        kernel, mesh=mesh,
+        in_specs=(P(None, heads, None), P(None, None, heads, None),
+                  P(None, None, heads, None), P(), P()),
+        out_specs=P(None, heads, None), check_vma=False)
 
 
 def _paged_attend(q: jax.Array, pool: "PagedKVCache", table: jax.Array,
@@ -291,9 +319,9 @@ def _paged_attend(q: jax.Array, pool: "PagedKVCache", table: jax.Array,
     bs = pool.k.shape[-3]
     nc = table.shape[1]
     if _use_paged_kernel():
-        from repro.kernels.paged_attention import paged_attention
         lens = jnp.broadcast_to(pos.astype(jnp.int32) + 1, (B,))
-        out = paged_attention(q[:, 0], pool.k, pool.v, table, lens)
+        out = _paged_kernel(pool.k.shape[-2])(q[:, 0], pool.k, pool.v,
+                                              table, lens)
         return out[:, None]
     kg = pool.k[table].reshape(B, nc * bs, *pool.k.shape[-2:])
     vg = pool.v[table].reshape(B, nc * bs, *pool.v.shape[-2:])

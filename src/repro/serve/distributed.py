@@ -12,7 +12,10 @@ with its arrays placed instead of its logic changed:
   resolved from :mod:`repro.sharding.rules` — the same trailing-dim rule
   table the training mesh uses, fitted to the serving mesh (axes a dim
   does not divide fall back to replicated);
-* paged KV **pools** shard their ``n_kv_heads`` axis over ``model``;
+* paged KV **pools** shard their ``n_kv_heads`` axis over ``model``; the
+  paged decode step is traced under the mesh (``jax.set_mesh``) so the
+  Pallas kernel, which GSPMD cannot partition, runs per KV-head shard
+  through ``shard_map``;
   contiguous KV caches come out of the jitted prefill already placed by
   GSPMD propagation from the sharded params;
 * paged **block tables** stay host-side numpy exactly as before and are
@@ -108,6 +111,15 @@ class ShardedServeEngine(ServeEngine):
             self._pools = jax.device_put(
                 self._pools,
                 rules.shardings_of(_pool_pspecs(self._pools, mesh), mesh))
+            # trace decode under the mesh: the Pallas paged kernel cannot
+            # be auto-partitioned and runs per KV-head shard instead
+            # (repro.models.attention._paged_kernel)
+            decode = self._decode_paged
+
+            def decode_in_mesh(*args):
+                with jax.set_mesh(mesh):
+                    return decode(*args)
+            self._decode_paged = decode_in_mesh
 
     def stats(self) -> Dict[str, Any]:
         out = super().stats()

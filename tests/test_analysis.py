@@ -84,6 +84,25 @@ def test_k001_misaligned_matmul_block():
     assert "K001" in _codes(_errors(diags))
 
 
+def test_k001_paged_attention_per_head_blocks():
+    # the per-(row, q head) launch the TPU lowering refuses: q (1, 1, D)
+    # over (B, Hq, D) and K/V (1, bs, 1, D) over the pool both put a 1
+    # against a head count in the second-minor dim
+    B, hq, hkv, d, bs, nc = 8, 16, 8, 128, 16, 128
+    pool = (RESERVED_BLOCKS + B * nc, bs, hkv, d)
+    old = ak.KernelCall(
+        kernel="paged_attention", site="per-head", grid=(B, hq, nc),
+        blocks={"q": ((1, 1, d), (B, hq, d)), "kv": ((1, bs, 1, d), pool)},
+        vmem_bytes=0, dtype_bytes=2, is_gemm=False)
+    errs = _errors(ak.check_call(old, get_target("tpu_v5e")))
+    assert _codes(errs) == {"K001"} and len(errs) == 2
+    # the launch the kernel makes: whole-head blocks, at qwen3 (D=128)
+    # and granite (D=64) widths
+    for d in (128, 64):
+        new = ak.describe_paged_attention(B, hq, d, nc, bs, n_kv_heads=hkv)
+        assert ak.check_call(new, get_target("tpu_v5e")) == []
+
+
 def test_k003_flash_attention_overflows_edge():
     call = ak.describe_flash_attention(1, 2048, 2048, 8, 128,
                                        bq=1024, bk=1024)

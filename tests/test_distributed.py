@@ -42,8 +42,8 @@ opt = adamw_init(params)
 m0 = Model(cfg)
 loss0, _ = jax.jit(m0.loss_fn)(params, batch)
 
-from repro.launch.mesh import make_mesh_compat
-mesh = make_mesh_compat((4, 2), ("data", "model"), jax.devices()[:8])
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((4, 2), ("data", "model"), jax.devices()[:8])
 model = S.build_model(cfg, mesh)
 step = S.make_train_step(cfg, model)
 with mesh, logical.set_rules(mesh, rules.logical_rules(mesh)):
@@ -78,8 +78,8 @@ logits0, caches0 = jax.jit(lambda p, b: model0.prefill(p, b, 32))(
 tok = jnp.argmax(logits0[:, 0], -1).astype(jnp.int32)[:, None]
 ref_logits, _ = jax.jit(model0.decode_step)(params, tok, caches0)
 
-from repro.launch.mesh import make_mesh_compat
-mesh = make_mesh_compat((4, 2), ("data", "model"), jax.devices()[:8])
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((4, 2), ("data", "model"), jax.devices()[:8])
 model = S.build_model(cfg, mesh)
 serve = S.make_serve_step(cfg, model)
 with mesh, logical.set_rules(mesh, rules.logical_rules(mesh, seq_shard=False)):
@@ -125,3 +125,22 @@ def test_dryrun_single_cell_smoke():
         env=ENV, capture_output=True, text=True, timeout=900)
     assert r.returncode == 0, r.stderr[-2000:]
     assert "[OK  ]" in r.stdout
+
+
+def test_compile_cache_dir_env_wins_else_checkout(monkeypatch, tmp_path):
+    import jax
+
+    from repro.launch.compile_cache import (CHECKOUT_CACHE_DIR,
+                                            enable_compile_cache)
+    old = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert enable_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == old  # JAX reads env
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        assert enable_compile_cache() == str(CHECKOUT_CACHE_DIR)
+        assert jax.config.jax_compilation_cache_dir == str(CHECKOUT_CACHE_DIR)
+        assert CHECKOUT_CACHE_DIR == Path(__file__).resolve().parents[1] \
+            / ".jax_cache"
+    finally:
+        jax.config.update("jax_compilation_cache_dir", old)

@@ -428,6 +428,50 @@ for layout in ("contiguous", "paged"):
     assert "OK contiguous" in r.stdout and "OK paged" in r.stdout
 
 
+def test_tp2_paged_pallas_decode_runs_per_head_shard():
+    """The Pallas paged-decode kernel cannot be GSPMD-partitioned, so a
+    sharded engine runs it per KV-head shard (shard_map over ``model``).
+    Forced onto the kernel (interpret mode here), tp=2 must reproduce
+    the tp=1 greedy tokens."""
+    code = """
+import jax, numpy as np
+from repro.configs import get_reduced_config
+from repro.launch.mesh import make_test_mesh
+from repro.models.model import init_params
+from repro.serve.distributed import ShardedServeEngine
+from repro.serve.engine import Request, ServeEngine
+from repro.serve.scheduler import SchedulerConfig
+
+cfg = get_reduced_config("qwen3_1_7b")
+params = init_params(jax.random.PRNGKey(0), cfg)
+sched = SchedulerConfig(page_size=8)
+
+def drain(eng):
+    rng = np.random.default_rng(0)
+    reqs = [Request(rid=i, prompt=rng.integers(0, cfg.vocab_size, 8).astype(
+                np.int32), max_new_tokens=5) for i in range(3)]
+    for r in reqs: eng.submit(r)
+    eng.run()
+    return {r.rid: r.output for r in reqs}
+
+want = drain(ServeEngine(cfg, params, max_batch=4, max_seq=16,
+                         scheduler=sched))
+eng = ShardedServeEngine(cfg, params, mesh=make_test_mesh(n_devices=2,
+                                                          model=2),
+                         max_batch=4, max_seq=16, scheduler=sched)
+got = drain(eng)
+assert got == want, (got, want)
+print("OK")
+"""
+    r = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                       text=True, timeout=600,
+                       env={**ENV, "REPRO_PAGED_BACKEND": "pallas",
+                            "XLA_FLAGS":
+                            "--xla_force_host_platform_device_count=2"})
+    assert r.returncode == 0, r.stderr[-3000:]
+    assert "OK" in r.stdout
+
+
 def test_tp2_artifact_round_trip_serves_sharded():
     """export(tp=2) -> load (validated against 4 host devices) ->
     ServeEngine.from_artifact auto-dispatches to the sharded engine on

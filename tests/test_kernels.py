@@ -239,3 +239,24 @@ def test_moe_gmm(e, c, k, n, dtype):
     np.testing.assert_allclose(
         np.asarray(out, np.float32), np.asarray(expect, np.float32),
         rtol=_tol(dtype), atol=_tol(dtype) * np.abs(np.asarray(expect)).max())
+
+
+# ---------------------------------------------------------------------------
+# interpret mode: the CPU default, never on a TPU
+# ---------------------------------------------------------------------------
+
+def test_interpret_mode_is_refused_on_tpu(monkeypatch):
+    from repro.core.oracle import MeasuredOracle, MeasurementConfig
+    from repro.kernels.ops import interpret_mode
+    monkeypatch.delenv("REPRO_PALLAS_INTERPRET", raising=False)
+    assert interpret_mode() is True                  # the CPU backend
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert interpret_mode() is False
+    assert interpret_mode(False) is False
+    with pytest.raises(RuntimeError, match="TPU"):
+        interpret_mode(True)
+    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "1")
+    with pytest.raises(RuntimeError, match="TPU"):
+        interpret_mode()
+    with pytest.raises(RuntimeError, match="TPU"):
+        MeasuredOracle(MeasurementConfig(interpret=True))._interpret()
