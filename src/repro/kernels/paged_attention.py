@@ -122,5 +122,6 @@ def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hq, D), q.dtype),
         interpret=interpret,
+        name="paged_attention",
     )(block_table.astype(jnp.int32), seq_lens.astype(jnp.int32),
       q, k_pool, v_pool)

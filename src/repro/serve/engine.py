@@ -17,6 +17,27 @@ Design (vLLM-style, sized down to what a CPU example can drive):
   * :meth:`run` is the legacy front door: a thin wrapper over
     ``serve_forever()`` with bit-identical greedy outputs.
 
+Spans (:mod:`repro.util.spans`) time the engine's host work on the
+profiler's clock and total it in ``stats()["spans"]``; their names are
+fixed, each indented under the span that holds it:
+
+  ``serve.step``         one :meth:`step`
+    ``serve.select``     the scheduler's choice of a cohort
+    ``serve.admit``      one admission (``cohort``, ``width``, ``rids``)
+      ``serve.prefill`` ``serve.kv_blocks`` ``serve.kv_scatter``
+      ``serve.sample`` ``serve.read_tokens`` ``serve.retire``
+    ``serve.tick``       one decode tick over every live group
+      ``serve.decode``   one group's decode call (``cohort``, ``width``,
+                         ``pos``)
+        ``serve.kv_table`` ``serve.dispatch`` ``serve.device_wait``
+        ``serve.sample`` ``serve.read_tokens`` ``serve.retire``
+      ``serve.chunk``    one chunk of a chunked prefill (``cohort``,
+                         ``pos``)
+
+``serve.dispatch`` ends when the jitted call returns, ``serve.device_wait``
+when its result is ready; ``serve.read_tokens`` is the per-row ``int()``
+reads. A cohort's id joins its admission to its decode calls.
+
 Engines optionally record their measured decode-step seconds into a
 :class:`~repro.core.oracle.MeasurementLog` (``measurements=``), which is
 how a serve run feeds the latency oracle that planned it — see
@@ -46,6 +67,7 @@ from repro.models.paged_cache import (RESERVED_BLOCKS, SCRATCH_BLOCK,
 from repro.serve.scheduler import (PagedSlotGroup, Scheduler,
                                    SchedulerConfig, SlotGroup)
 from repro.util.faults import FaultInjector, StragglerMonitor
+from repro.util.spans import Spans
 
 
 @dataclasses.dataclass
@@ -149,6 +171,8 @@ class ServeEngine:
         self.faults = faults
         self.fault_tag = fault_tag or self.measurement_tag
         self.straggler = straggler
+        self.spans = Spans()
+        self._next_cohort = 0
         # physically copied cache rows (engine-owned; every SlotGroup's
         # compact() increments it — the paged layout's zero-copy gate)
         self._copy_counter = {"rows": 0}
@@ -180,9 +204,9 @@ class ServeEngine:
                                        donate_argnums=2)
             # prefill padded to the cohort's block multiple, not max_seq —
             # short prompts don't pay full-length attention at admission
-            self._prefill_padded = jax.jit(
-                lambda p, b, ms: self.model.prefill(p, b, ms),
-                static_argnums=2)
+            def prefill_padded(params, batch, padded_len):
+                return self.model.prefill(params, batch, padded_len)
+            self._prefill_padded = jax.jit(prefill_padded, static_argnums=2)
         # paged-KV sanitizer (repro.analysis.kv_sanitizer) at every
         # quantum boundary: SchedulerConfig(debug_kv=True), or
         # REPRO_DEBUG_KV=1 to flip it on without touching call sites
@@ -190,8 +214,10 @@ class ServeEngine:
             self.scheduler.config.debug_kv
             or os.environ.get("REPRO_DEBUG_KV", "0") not in ("", "0"))
         self.reset_stats()
-        self._prefill = jax.jit(
-            lambda p, b: self.model.prefill(p, b, max_seq))
+
+        def prefill(params, batch):
+            return self.model.prefill(params, batch, max_seq)
+        self._prefill = jax.jit(prefill)
         self._decode = jax.jit(self.model.decode_step)
 
     @classmethod
@@ -298,14 +324,11 @@ class ServeEngine:
         live group one decode token; otherwise reports ``idle``. Returns
         a small event record — callers interleave ``step()`` with their
         own work (the router round-robins it across engines)."""
-        t0 = time.perf_counter()
-        try:
+        # wall time (``serve.step``) accrues per quantum, so an engine
+        # driven by an external loop (the router round-robin) still
+        # reports a meaningful tokens_per_s
+        with self.spans.span("serve.step"):
             result = self._step_inner()
-        finally:
-            # wall time accrues per quantum, so an engine driven by an
-            # external loop (the router round-robin) still reports a
-            # meaningful tokens_per_s
-            self._wall_s += time.perf_counter() - t0
         if self._debug_kv:
             self._kv_debug_sweep()
         return result
@@ -325,7 +348,9 @@ class ServeEngine:
 
     def _step_inner(self) -> Dict[str, Any]:
         free = self.max_batch - sum(g.width for g in self.groups)
-        batch = self.scheduler.select(free, live_groups=len(self.groups))
+        with self.spans.span("serve.select"):
+            batch = self.scheduler.select(free,
+                                          live_groups=len(self.groups))
         if batch:
             try:
                 self._admit(batch)
@@ -372,22 +397,34 @@ class ServeEngine:
     # -- internal: admission + decode ---------------------------------------
 
     def _admit(self, reqs: List[Request]) -> SlotGroup:
-        if self.faults is not None:
-            self.faults.fire("prefill", self.fault_tag)
-        if self.kv_layout == "paged":
-            return self._admit_paged(reqs)
+        cohort = self._next_cohort
+        self._next_cohort += 1
+        with self.spans.span("serve.admit", cohort=cohort, width=len(reqs),
+                             rids=" ".join(str(r.rid) for r in reqs)):
+            if self.faults is not None:
+                self.faults.fire("prefill", self.fault_tag)
+            if self.kv_layout == "paged":
+                group = self._admit_paged(reqs)
+            else:
+                group = self._admit_contiguous(reqs)
+        group.cohort = cohort
+        return group
+
+    def _admit_contiguous(self, reqs: List[Request]) -> SlotGroup:
         plen = len(reqs[0].prompt)
         toks = np.zeros((len(reqs), plen), np.int32)
         for i, r in enumerate(reqs):
             toks[i] = r.prompt
-        logits, caches = self._prefill(self.params,
-                                       {"tokens": jnp.asarray(toks)})
+        with self.spans.span("serve.prefill"):
+            logits, caches = self._prefill(self.params,
+                                           {"tokens": jnp.asarray(toks)})
         t_first = time.time()
         for r in reqs:
             r.t_first_token = t_first
         cur = self._sample(logits, reqs)
-        for i, r in enumerate(reqs):
-            r.output.append(int(cur[i, 0]))
+        with self.spans.span("serve.read_tokens"):
+            for i, r in enumerate(reqs):
+                r.output.append(int(cur[i, 0]))
         self._prefills += 1
         self._prefill_tokens += len(reqs) * plen
         self._live_kv_slots += len(reqs) * self.max_seq
@@ -434,72 +471,75 @@ class ServeEngine:
         # only the returned cache is block-padded — its slots past plen
         # hold garbage at absolute positions the causal mask hides until
         # decode overwrites them
-        logits_u, caches = self._prefill_padded(
-            self.params, {"tokens": jnp.asarray(np.stack(u_prompts))},
-            padded)
+        with self.spans.span("serve.prefill"):
+            logits_u, caches = self._prefill_padded(
+                self.params, {"tokens": jnp.asarray(np.stack(u_prompts))},
+                padded)
 
         # block tables: one canonical table per unique prompt, built
         # column by column against the share registry; later rows with
         # the same prompt incref the full columns and get a private
         # frontier block (scattered from the same prefill row)
-        rows_s: List[int] = []   # scatter worklist into the U prefill rows
-        cols_s: List[int] = []
-        bids_s: List[int] = []
-        # every reference acquired below, in order — pool exhaustion
-        # mid-table must return them all before the cohort is re-queued,
-        # or the pool shrinks for good (a V001 leak under debug_kv)
-        acquired: List[int] = []
-        u_tables = np.zeros((U, ncb), np.int32)
-        try:
-            for u, p in enumerate(u_prompts):
-                for j in range(ncb):
-                    full = (j + 1) * bs <= plen
-                    bid = None
-                    if share and full:
-                        # plen and U are part of the key: k/v bits can
-                        # differ across padded lengths / batch widths, and
-                        # a shared block must be byte-for-byte one
-                        # computation
-                        key = (plen, U, p[:(j + 1) * bs].tobytes())
-                        bid = alloc.share(key)
-                        if bid is not None:
-                            acquired.append(bid)
+        with self.spans.span("serve.kv_blocks"):
+            rows_s: List[int] = []   # scatter worklist into the U prefill rows
+            cols_s: List[int] = []
+            bids_s: List[int] = []
+            # every reference acquired below, in order — pool exhaustion
+            # mid-table must return them all before the cohort is re-queued,
+            # or the pool shrinks for good (a V001 leak under debug_kv)
+            acquired: List[int] = []
+            u_tables = np.zeros((U, ncb), np.int32)
+            try:
+                for u, p in enumerate(u_prompts):
+                    for j in range(ncb):
+                        full = (j + 1) * bs <= plen
+                        bid = None
+                        if share and full:
+                            # plen and U are part of the key: k/v bits can
+                            # differ across padded lengths / batch widths, and
+                            # a shared block must be byte-for-byte one
+                            # computation
+                            key = (plen, U, p[:(j + 1) * bs].tobytes())
+                            bid = alloc.share(key)
+                            if bid is not None:
+                                acquired.append(bid)
+                            else:
+                                bid = alloc.alloc()
+                                acquired.append(bid)
+                                alloc.publish(key, bid)
+                                rows_s.append(u); cols_s.append(j)
+                                bids_s.append(bid)
                         else:
                             bid = alloc.alloc()
                             acquired.append(bid)
-                            alloc.publish(key, bid)
-                            rows_s.append(u); cols_s.append(j)
-                            bids_s.append(bid)
-                    else:
-                        bid = alloc.alloc()
-                        acquired.append(bid)
-                        rows_s.append(u); cols_s.append(j); bids_s.append(bid)
-                    u_tables[u, j] = bid
-            table = np.zeros((W, ncb), np.int32)
-            seen_u: Dict[int, int] = {}
-            frontier = ncb - 1 if plen % bs else None
-            for i in range(W):
-                u = row_to_u[i]
-                if u not in seen_u:
-                    seen_u[u] = i
-                    table[i] = u_tables[u]
-                    continue
-                for j in range(ncb):
-                    if j == frontier:
-                        bid = alloc.alloc()  # private frontier per duplicate
-                        acquired.append(bid)
-                        rows_s.append(u); cols_s.append(j); bids_s.append(bid)
-                    else:
-                        bid = int(u_tables[u, j])
-                        alloc.incref(bid, shared=True)
-                        acquired.append(bid)
-                    table[i, j] = bid
-        except BaseException:
-            for bid in reversed(acquired):
-                alloc.decref(bid)
-            raise
-        self._pools = scatter_prefill_blocks(
-            self._pools, caches, rows_s, cols_s, bids_s, block_size=bs)
+                            rows_s.append(u); cols_s.append(j); bids_s.append(bid)
+                        u_tables[u, j] = bid
+                table = np.zeros((W, ncb), np.int32)
+                seen_u: Dict[int, int] = {}
+                frontier = ncb - 1 if plen % bs else None
+                for i in range(W):
+                    u = row_to_u[i]
+                    if u not in seen_u:
+                        seen_u[u] = i
+                        table[i] = u_tables[u]
+                        continue
+                    for j in range(ncb):
+                        if j == frontier:
+                            bid = alloc.alloc()  # private frontier per duplicate
+                            acquired.append(bid)
+                            rows_s.append(u); cols_s.append(j); bids_s.append(bid)
+                        else:
+                            bid = int(u_tables[u, j])
+                            alloc.incref(bid, shared=True)
+                            acquired.append(bid)
+                        table[i, j] = bid
+            except BaseException:
+                for bid in reversed(acquired):
+                    alloc.decref(bid)
+                raise
+        with self.spans.span("serve.kv_scatter"):
+            self._pools = scatter_prefill_blocks(
+                self._pools, caches, rows_s, cols_s, bids_s, block_size=bs)
 
         t_first = time.time()
         for r in reqs:
@@ -507,8 +547,9 @@ class ServeEngine:
         logits = logits_u if U == W else jnp.take(
             logits_u, jnp.asarray(row_to_u, jnp.int32), axis=0)
         cur = self._sample(logits, reqs)
-        for i, r in enumerate(reqs):
-            r.output.append(int(cur[i, 0]))
+        with self.spans.span("serve.read_tokens"):
+            for i, r in enumerate(reqs):
+                r.output.append(int(cur[i, 0]))
         self._prefills += 1
         self._prefill_tokens += U * plen
         group = PagedSlotGroup(reqs, table, cur, plen, allocator=alloc,
@@ -532,20 +573,21 @@ class ServeEngine:
         n_chunks = -(-plen // C)
         total_cols = n_chunks * C // bs
         ncb_real = -(-plen // bs)
-        table = np.full((W, total_cols), SCRATCH_BLOCK, np.int32)
-        acquired: List[int] = []
-        try:
-            for i in range(W):
-                for j in range(ncb_real):
-                    bid = alloc.alloc()
-                    acquired.append(bid)
-                    table[i, j] = bid
-        except BaseException:
-            # pool exhausted mid-table: return every block already taken
-            # before the cohort is re-queued, or they leak for good
-            for bid in reversed(acquired):
-                alloc.decref(bid)
-            raise
+        with self.spans.span("serve.kv_blocks"):
+            table = np.full((W, total_cols), SCRATCH_BLOCK, np.int32)
+            acquired: List[int] = []
+            try:
+                for i in range(W):
+                    for j in range(ncb_real):
+                        bid = alloc.alloc()
+                        acquired.append(bid)
+                        table[i, j] = bid
+            except BaseException:
+                # pool exhausted mid-table: return every block already taken
+                # before the cohort is re-queued, or they leak for good
+                for bid in reversed(acquired):
+                    alloc.decref(bid)
+                raise
         prompt_padded = np.zeros((W, n_chunks * C), np.int32)
         for i, r in enumerate(reqs):
             prompt_padded[i, :plen] = r.prompt
@@ -561,33 +603,48 @@ class ServeEngine:
     def _decode_tick(self) -> int:
         new_tokens = 0
         self._ticks += 1
-        for group in list(self.groups):
-            if isinstance(group, PagedSlotGroup) and group.prefilling:
-                self._chunk_tick(group)
-                continue
-            t0 = time.perf_counter()
+        with self.spans.span("serve.tick"):
+            for group in list(self.groups):
+                if isinstance(group, PagedSlotGroup) and group.prefilling:
+                    self._chunk_tick(group)
+                else:
+                    new_tokens += self._decode_group(group)
+        return new_tokens
+
+    def _decode_group(self, group: SlotGroup) -> int:
+        """One decode call for ``group``, then its sampling, token reads
+        and retirement; returns the tokens it added."""
+        paged = isinstance(group, PagedSlotGroup)
+        span = self.spans.span
+        with span("serve.decode", cohort=group.cohort, width=group.width,
+                  pos=group.pos if paged else -1) as decode:
             if self.faults is not None:
                 # inside the timed region: a delay spec shows up as a
                 # slow step (the straggler monitor must see it), a crash
                 # spec kills the tick with the group state untouched
                 self.faults.fire("decode", self.fault_tag)
-            if isinstance(group, PagedSlotGroup):
-                if group.pos % group.block_size == 0:
-                    # decode is about to cross into a new block-table
-                    # column (prefill filled columns 0..ceil(plen/bs)-1)
-                    group.ensure_frontier()
-                logits, self._pools = self._decode_paged(
-                    self.params, group.cur, self._pools,
-                    group.device_table(), jnp.int32(group.pos))
+            if paged:
+                with span("serve.kv_table"):
+                    if group.pos % group.block_size == 0:
+                        # decode is about to cross into a new block-table
+                        # column (prefill filled columns 0..ceil(plen/bs)-1)
+                        group.ensure_frontier()
+                    table = group.device_table()
+                with span("serve.dispatch"):
+                    logits, self._pools = self._decode_paged(
+                        self.params, group.cur, self._pools, table,
+                        jnp.int32(group.pos))
                 group.pos += 1
             else:
-                logits, group.caches = self._decode(self.params, group.cur,
-                                                    group.caches)
-            jax.block_until_ready(logits)
-            dt = time.perf_counter() - t0
+                with span("serve.dispatch"):
+                    logits, group.caches = self._decode(
+                        self.params, group.cur, group.caches)
+            with span("serve.device_wait") as wait:
+                jax.block_until_ready(logits)
+            # the timed step: from the fault point to the device's result
+            dt = wait.t1 - decode.t0
             if self.straggler is not None:
                 self.straggler.observe(dt)
-            self._decode_wall_s += dt
             self._step_times.append(dt)
             self._step_widths.append(group.width)
             self._decode_steps += 1
@@ -595,10 +652,12 @@ class ServeEngine:
             self._active_slot_steps += sum(
                 1 for r in group.requests if r is not None)
             group.cur = self._sample(logits, group.requests)
-            for i, r in enumerate(group.requests):
-                if r is not None and len(r.output) < r.max_new_tokens:
-                    r.output.append(int(group.cur[i, 0]))
-                    new_tokens += 1
+            new_tokens = 0
+            with span("serve.read_tokens"):
+                for i, r in enumerate(group.requests):
+                    if r is not None and len(r.output) < r.max_new_tokens:
+                        r.output.append(int(group.cur[i, 0]))
+                        new_tokens += 1
             self._retire(group)
         return new_tokens
 
@@ -608,57 +667,61 @@ class ServeEngine:
         C = self.scheduler.config.prefill_chunk
         c = group.chunks_done
         start = c * C
-        toks = jnp.asarray(group.prompt_padded[:, start:start + C])
-        last = min(group.plen - 1 - start, C - 1)
-        logits, self._pools = self._chunk_step(
-            self.params, toks, self._pools, group.device_table(),
-            jnp.int32(start), jnp.int32(last))
-        jax.block_until_ready(logits)
-        group.chunks_done += 1
-        self._chunk_steps += 1
-        self._prefill_tokens += group.width * C
-        if not group.prefilling:
-            t_first = time.time()
-            for r in group.requests:
-                if r is not None:
-                    r.t_first_token = t_first
-            group.cur = self._sample(logits, group.requests)
-            for i, r in enumerate(group.requests):
-                if r is not None:
-                    r.output.append(int(group.cur[i, 0]))
-            self._retire(group)
+        with self.spans.span("serve.chunk", cohort=group.cohort, pos=start):
+            toks = jnp.asarray(group.prompt_padded[:, start:start + C])
+            last = min(group.plen - 1 - start, C - 1)
+            logits, self._pools = self._chunk_step(
+                self.params, toks, self._pools, group.device_table(),
+                jnp.int32(start), jnp.int32(last))
+            jax.block_until_ready(logits)
+            group.chunks_done += 1
+            self._chunk_steps += 1
+            self._prefill_tokens += group.width * C
+            if not group.prefilling:
+                t_first = time.time()
+                for r in group.requests:
+                    if r is not None:
+                        r.t_first_token = t_first
+                group.cur = self._sample(logits, group.requests)
+                with self.spans.span("serve.read_tokens"):
+                    for i, r in enumerate(group.requests):
+                        if r is not None:
+                            r.output.append(int(group.cur[i, 0]))
+                self._retire(group)
 
     def _retire(self, group: SlotGroup) -> None:
         """Move finished requests out of their rows, drop the group when
         empty, and compact the surviving rows (freed slots return to the
         global budget, so the next cohort can be admitted mid-decode)."""
-        now = time.time()
-        for i, r in enumerate(group.requests):
-            if r is not None and len(r.output) >= r.max_new_tokens:
-                r.done, r.t_done = True, now
-                self.done.append(r)
-                group.requests[i] = None
-        if all(r is None for r in group.requests):
-            self.groups.remove(group)
-            if isinstance(group, PagedSlotGroup):
-                group.release()   # refcounts drop; orphaned blocks free
-            else:
-                self._live_kv_slots -= group.width * self.max_seq
-            return
-        freed = group.compact(self.scheduler.config.compact)
-        if freed and not isinstance(group, PagedSlotGroup):
-            self._live_kv_slots -= freed * self.max_seq
+        with self.spans.span("serve.retire"):
+            now = time.time()
+            for i, r in enumerate(group.requests):
+                if r is not None and len(r.output) >= r.max_new_tokens:
+                    r.done, r.t_done = True, now
+                    self.done.append(r)
+                    group.requests[i] = None
+            if all(r is None for r in group.requests):
+                self.groups.remove(group)
+                if isinstance(group, PagedSlotGroup):
+                    group.release()   # refcounts drop; orphaned blocks free
+                else:
+                    self._live_kv_slots -= group.width * self.max_seq
+                return
+            freed = group.compact(self.scheduler.config.compact)
+            if freed and not isinstance(group, PagedSlotGroup):
+                self._live_kv_slots -= freed * self.max_seq
 
     def _sample(self, logits: jax.Array,
                 rows: List[Optional[Request]]) -> jax.Array:
-        self.key, sub = jax.random.split(self.key)
-        greedy = jnp.argmax(logits[:, 0], axis=-1)
-        temps = jnp.asarray([r.temperature if r is not None else 0.0
-                             for r in rows])[:, None]
-        noisy = jax.random.categorical(
-            sub, logits[:, 0] / jnp.maximum(temps, 1e-6))
-        tok = jnp.where(temps[:, 0] > 0, noisy, greedy)
-        return tok[:, None].astype(jnp.int32)
+        with self.spans.span("serve.sample"):
+            self.key, sub = jax.random.split(self.key)
+            greedy = jnp.argmax(logits[:, 0], axis=-1)
+            temps = jnp.asarray([r.temperature if r is not None else 0.0
+                                 for r in rows])[:, None]
+            noisy = jax.random.categorical(
+                sub, logits[:, 0] / jnp.maximum(temps, 1e-6))
+            tok = jnp.where(temps[:, 0] > 0, noisy, greedy)
+            return tok[:, None].astype(jnp.int32)
 
     # -- stats + measurement feedback ---------------------------------------
 
@@ -670,12 +733,11 @@ class ServeEngine:
         self._prefills = 0
         self._ticks = 0
         self._decode_steps = 0
-        self._decode_wall_s = 0.0
         self._slot_steps = 0
         self._active_slot_steps = 0
         self._step_times: List[float] = []
         self._step_widths: List[int] = []
-        self._wall_s = 0.0
+        self.spans.reset()
         self._prefill_tokens = 0
         self._chunk_steps = 0
         self._copy_counter["rows"] = 0
@@ -724,14 +786,14 @@ class ServeEngine:
         total_tokens = sum(len(r.output) for r in self.done)
         ttfts = [r.t_first_token - r.t_submit for r in self.done]
         decodes = [r.t_done - r.t_first_token for r in self.done]
+        spans = self.spans.totals()
+        wall_s = spans.get("serve.step", {}).get("s", 0.0)
         stats = {
             "requests": len(self.done),
-            "waves": self._prefills,          # legacy name for prefills
             "prefills": self._prefills,
             "total_new_tokens": total_tokens,
-            "wall_s": self._wall_s,
-            "tokens_per_s": total_tokens / max(self._wall_s, 1e-9),
-            "mean_ttft_s": float(np.mean(ttfts)) if ttfts else 0.0,
+            "wall_s": wall_s,
+            "tokens_per_s": total_tokens / max(wall_s, 1e-9),
             # tail latency: TTFT and per-request decode time across
             # requests, plus per-decode-step percentiles — the serve-time
             # check for the planner's per-step latency claims
@@ -758,7 +820,7 @@ class ServeEngine:
                                 if self.straggler is not None else 0),
             # predicted-vs-measured step latency: how wrong the latency
             # oracle is on the model that is actually executing
-            "measured_step_s": self._decode_wall_s / self._decode_steps
+            "measured_step_s": sum(self._step_times) / self._decode_steps
             if self._decode_steps else 0.0,
             "predicted_step_s": self.predicted_step_s,
             # KV storage accounting. kv_row_copies counts physically
@@ -785,6 +847,9 @@ class ServeEngine:
                 * self.scheduler.config.page_size * self._kv_row_bytes
                 if self.kv_layout == "paged"
                 else self._peak_kv_slots * self._kv_row_bytes),
+            # host spans by name (cumulative since reset_stats): count,
+            # seconds, and seconds not covered by child spans
+            "spans": spans,
         }
         if self.predicted_step_s is not None and self._decode_steps:
             meas = stats["measured_step_s"]

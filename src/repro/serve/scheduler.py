@@ -241,6 +241,9 @@ class SlotGroup:
     #: engine-owned mutable dict {"rows": int} counting physically copied
     #: cache rows (the paged layout's zero-copy claim is asserted on it)
     copy_counter: Optional[Dict[str, int]] = None
+    #: the engine's id for the admission that made the group; its spans
+    #: (``serve.admit``, ``serve.decode``) carry it as ``cohort``
+    cohort: int = -1
 
     def __init__(self, requests: List[Any], caches: Dict[str, Any], cur,
                  plen: int):

@@ -110,7 +110,7 @@ def test_engine_batches_multiple_requests(setup):
         eng.submit(Request(rid=i, prompt=p, max_new_tokens=4))
     stats = eng.run()
     assert stats["requests"] == 6
-    assert stats["waves"] == 2          # 4 + 2 with max_batch=4
+    assert stats["prefills"] == 2       # 4 + 2 with max_batch=4
     assert stats["total_new_tokens"] == 24
     # batching must not cross-contaminate: request 0 alone == in batch
     solo = ServeEngine(cfg, params, max_batch=1, max_seq=24)
@@ -129,7 +129,7 @@ def test_engine_mixed_length_prompts_wave_correctly(setup):
             0, cfg.vocab_size, size=L).astype(np.int32), max_new_tokens=2))
     stats = eng.run()
     assert stats["requests"] == 5
-    assert stats["waves"] >= 2          # length groups cannot share a wave
+    assert stats["prefills"] >= 2       # length groups cannot share a wave
 
 
 def test_engine_serves_real_pruned_params_end_to_end():
@@ -162,7 +162,7 @@ def test_engine_serves_real_pruned_params_end_to_end():
     stats = eng.run()
     # batch accounting: every request finished with exactly its token budget
     assert stats["requests"] == n_req
-    assert stats["waves"] == 2                    # 4 + 2 with max_batch=4
+    assert stats["prefills"] == 2                 # 4 + 2 with max_batch=4
     assert stats["total_new_tokens"] == n_req * n_new
     for r in eng.done:
         assert r.done and len(r.output) == n_new
@@ -260,7 +260,7 @@ def test_engine_keeps_batches_full_on_interleaved_prompt_lengths(setup):
     stats = eng.run()
     assert stats["requests"] == 8
     # every admitted cohort was a full batch of one prompt length
-    assert stats["waves"] == 2
+    assert stats["prefills"] == 2
     assert stats["mean_batch_occupancy"] == pytest.approx(1.0)
     assert stats["slot_steps"] == stats["active_slot_steps"]
 
