@@ -16,9 +16,10 @@ random bf16 parameters from a seed, built through the same calls as
 ``ServeEngine`` with the default paged scheduler) and fed the launcher's
 own request mix. It checks that every request completes with its token
 count, that the compiled decode step holds the Pallas paged-attention
-kernel (``tpu_custom_call``), and that the first decode steps' log-probs
+kernel (``tpu_custom_call``), that the first decode steps' log-probs
 agree with a kernel-free reference forward over the same tokens within
-:func:`logprob_tol`.
+:func:`logprob_tol`, and that each greedy row's token, sampled inside the
+decode program, is the argmax of those logits (:func:`check_greedy_tokens`).
 
 Plan phase (one chip): tunes a few qwen3 GEMMs and prices one paged
 decode attention under the ``measured`` oracle, whose timings must be
@@ -26,7 +27,8 @@ finite, positive and taken from compiled kernels.
 
 Four-chip phase (``--chips 4``, and nothing else): ``ShardedServeEngine``
 at tp=4 on a (1, 4) mesh against tp=1 on device 0 within the same
-tolerance, then a ``ReplicaSupervisor`` of four one-chip replicas, one
+tolerance, each with its greedy tokens checked as above, then a
+``ReplicaSupervisor`` of four one-chip replicas, one
 per device, that must complete every request with zero crashes.
 
 Every time printed is host wall-clock on the chip's machine and is
@@ -53,11 +55,19 @@ REF_STEPS = 4            # decode steps compared against the reference
 # while a kernel that reads the wrong head group or drops the newest
 # slot misses by 70 ulps or more.
 LOGPROB_ULPS = 8
+# A greedy token may miss the argmax of the logits recorded beside it only
+# by a tie this close: the recorded logits come from the model's step
+# compiled alone, the token from the same step compiled with its sampling.
+TIE_ULPS = 2
+
+
+def _bf16_ulp(x: float) -> float:
+    import math
+    return 2.0 ** (math.floor(math.log2(max(abs(x), 2.0 ** -126))) - 7)
 
 
 def logprob_tol(max_abs_logit: float) -> float:
-    import math
-    return LOGPROB_ULPS * 2.0 ** (math.floor(math.log2(max_abs_logit)) - 7)
+    return LOGPROB_ULPS * _bf16_ulp(max_abs_logit)
 
 
 class SmokeError(AssertionError):
@@ -86,27 +96,36 @@ def _requests(cfg, n):
 def _record_decode(eng, steps):
     """Wrap the engine's own jitted paged decode step so its first
     ``steps`` calls leave (row rids, input tokens, pos, logits) behind.
-    The step itself is untouched; the first call's argument shapes are
-    kept to lower it again for inspection."""
+    The step samples inside its program, so the logits come from the
+    model's decode step jitted alone on the same inputs, before the step
+    takes the pools. The step itself is untouched; the first call's
+    argument shapes are kept to lower it again for inspection."""
+    import contextlib
+
     import jax
     import numpy as np
     step = eng._decode_paged
+    logits_of = jax.jit(eng.model.decode_step_paged)
+    mesh = getattr(eng, "mesh", None)
     seen = {"calls": [], "shapes": None}
 
-    def recording(params, cur, pools, table, pos):
+    def recording(*args):
+        params, cur, pools, table, pos = args[:5]
         if seen["shapes"] is None:
             seen["shapes"] = jax.tree.map(
-                lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
-                                               sharding=x.sharding),
-                (params, cur, pools, table, pos))
-        logits, pools = step(params, cur, pools, table, pos)
+                lambda x: jax.ShapeDtypeStruct(
+                    x.shape, x.dtype, sharding=getattr(x, "sharding", None)),
+                args)
         if len(seen["calls"]) < steps:
             _check(len(eng.groups) == 1, "expected one decoding cohort")
             rids = [r.rid if r is not None else None
                     for r in eng.groups[0].requests]
+            with (jax.set_mesh(mesh) if mesh is not None
+                  else contextlib.nullcontext()):
+                logits, _ = logits_of(params, cur, pools, table, pos)
             seen["calls"].append((rids, np.asarray(cur[:, 0]), int(pos),
                                   np.asarray(logits[:, 0], np.float32)))
-        return logits, pools
+        return step(*args)
 
     eng._decode_paged = recording
     return step, seen
@@ -198,6 +217,30 @@ def reference_logprob_diff(cfg, params, reqs, calls):
     return diff, agree / max(diff.rows, 1)
 
 
+def check_greedy_tokens(by_rid, calls, what):
+    """Tie the tokens the decode program sampled to the logits that were
+    checked: decode call ``j`` emitted ``output[j + 1]``, which for every
+    greedy row must be the argmax of that row's recorded logits, or within
+    :data:`TIE_ULPS` bf16 ulps of it."""
+    import numpy as np
+    rows = exact = 0
+    for j, (row_rids, _, _, logits) in enumerate(calls):
+        for i, rid in enumerate(row_rids):
+            if rid is None or by_rid[rid].temperature > 0:
+                continue
+            got, tok = logits[i], by_rid[rid].output[j + 1]
+            top = float(got.max())
+            _check(got[tok] >= top - TIE_ULPS * _bf16_ulp(top),
+                   f"{what}: decode call {j} row {i} emitted token {tok} "
+                   f"(logit {got[tok]}), the argmax logit is {top}")
+            rows += 1
+            exact += int(tok == int(np.argmax(got)))
+    _say(f"{what}: {exact} of {rows} greedy tokens sampled in the decode "
+         f"program equal the argmax of the recorded logits, the rest lie "
+         f"within {TIE_ULPS} bf16 ulps of it")
+    _check(rows > 0, f"{what}: no greedy row-steps compared")
+
+
 def _peak_bytes(device):
     stats = device.memory_stats() or {}
     return stats.get("peak_bytes_in_use", "not reported")
@@ -243,6 +286,7 @@ def serve_phase(cfg, *, expect_kernel=True):
     _say(f"serve: greedy agreement with the reference {agree:.3f} "
          f"(informational: random-init logits are near ties)")
     diff.check("serve vs kernel-free reference")
+    check_greedy_tokens({r.rid: r for r in reqs}, seen["calls"], "serve")
     _say(f"serve: peak_bytes_in_use {_peak_bytes(jax.devices()[0])}")
 
 
@@ -306,6 +350,7 @@ def four_chip_phase(cfg):
         reqs = _requests(cfg, N_REQUESTS)
         _drain(eng, reqs)
         runs[tp] = ({r.rid: r for r in reqs}, seen["calls"])
+        check_greedy_tokens(*runs[tp], f"tp={tp}")
         _say(f"tp={tp}: {N_REQUESTS} requests served in "
              f"{time.perf_counter() - t0:.3f} s (compile included)")
         del eng

@@ -46,6 +46,7 @@ J002/J003 — which are outright serving bugs — are errors.
 """
 from __future__ import annotations
 
+import re
 from typing import Any, Dict, List, Optional, Set, Tuple
 
 import jax
@@ -356,17 +357,25 @@ def audit_engine_donation(engine) -> List[Diagnostic]:
     cur = _sds((engine.max_batch, 1), np.int32)
     table = _sds((engine.max_batch, n_cols), np.int32)
     pos = _sds((), np.int32)
+    temps = _sds((engine.max_batch,), np.float32)
+    key = _abstract(engine.key)
     checks = [("decode_step_paged",
                lambda: engine._decode_paged.lower(params, cur, pools,
-                                                  table, pos))]
+                                                  table, pos, temps, key))]
     if sc.prefill_chunk:
         toks = _sds((engine.max_batch, sc.prefill_chunk), np.int32)
         checks.append(("prefill_chunk_paged",
                        lambda: engine._chunk_step.lower(
                            params, toks, pools, table, pos, pos)))
+    # both steps return the pools right after their first output, so the
+    # pool leaves are flat outputs 1..n; other donated arguments (the
+    # decode step's PRNG key) must not stand in for them
+    pool_outputs = set(range(1, 1 + len(jax.tree.leaves(pools))))
     for name, lower in checks:
         text = lower().as_text()
-        if "aliasing_output" not in text:
+        aliased = {int(i) for i in
+                   re.findall(r"tf\.aliasing_output = (\d+)", text)}
+        if not pool_outputs <= aliased:
             out.append(Diagnostic(
                 "J003", ERROR, f"engine.{name}",
                 "the block pools are not donated into the jitted step — "

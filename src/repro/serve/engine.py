@@ -30,13 +30,18 @@ fixed, each indented under the span that holds it:
       ``serve.decode``   one group's decode call (``cohort``, ``width``,
                          ``pos``)
         ``serve.kv_table`` ``serve.dispatch`` ``serve.device_wait``
-        ``serve.sample`` ``serve.read_tokens`` ``serve.retire``
+        ``serve.read_tokens`` ``serve.retire``
       ``serve.chunk``    one chunk of a chunked prefill (``cohort``,
-                         ``pos``)
+                         ``pos``); the last one also holds
+        ``serve.sample`` ``serve.read_tokens`` ``serve.retire``
 
+A decode call is one jitted program that runs the model step and samples
+inside it (:func:`sample_tokens`), so ``serve.sample`` times only the
+first token of an admission or of a finished chunked prefill.
 ``serve.dispatch`` ends when the jitted call returns, ``serve.device_wait``
-when its result is ready; ``serve.read_tokens`` is the per-row ``int()``
-reads. A cohort's id joins its admission to its decode calls.
+when its sampled tokens are ready; ``serve.read_tokens`` is one transfer
+of those tokens to the host. A cohort's id joins its admission to its
+decode calls.
 
 Engines optionally record their measured decode-step seconds into a
 :class:`~repro.core.oracle.MeasurementLog` (``measurements=``), which is
@@ -68,6 +73,38 @@ from repro.serve.scheduler import (PagedSlotGroup, Scheduler,
                                    SchedulerConfig, SlotGroup)
 from repro.util.faults import FaultInjector, StragglerMonitor
 from repro.util.spans import Spans
+
+
+def sample_tokens(logits: jax.Array, temps: jax.Array, key: jax.Array):
+    """Next tokens from last-position logits ``(W, 1, V)``: greedy where
+    ``temps`` ``(W,)`` is 0, a categorical draw at that temperature
+    elsewhere. Splits ``key`` once and returns ``(tokens (W, 1) int32,
+    new key)``. Pure, so it traces into the decode programs."""
+    key, sub = jax.random.split(key)
+    greedy = jnp.argmax(logits[:, 0], axis=-1)
+    noisy = jax.random.categorical(
+        sub, logits[:, 0] / jnp.maximum(temps[:, None], 1e-6))
+    tok = jnp.where(temps > 0, noisy, greedy)
+    return tok[:, None].astype(jnp.int32), key
+
+
+def sampled_decode_steps(model: Model):
+    """The model's contiguous and paged decode steps, each followed by
+    :func:`sample_tokens` in the same program: they take ``temps`` and
+    the key after the step's own arguments and return ``(tokens, caches
+    or pools, new key)``. The names keep the programs' names
+    (``jit_decode_step``, ``jit_decode_step_paged``)."""
+    def decode_step(params, cur, caches, temps, key):
+        logits, caches = model.decode_step(params, cur, caches)
+        tok, key = sample_tokens(logits, temps, key)
+        return tok, caches, key
+
+    def decode_step_paged(params, cur, pools, table, pos, temps, key):
+        logits, pools = model.decode_step_paged(params, cur, pools, table,
+                                                pos)
+        tok, key = sample_tokens(logits, temps, key)
+        return tok, pools, key
+    return decode_step, decode_step_paged
 
 
 @dataclasses.dataclass
@@ -184,6 +221,7 @@ class ServeEngine:
         self._live_kv_slots = 0   # contiguous: currently allocated slots
         self._peak_kv_slots = 0
         self.kv_allocator: Optional[BlockAllocator] = None
+        decode, decode_paged = sampled_decode_steps(self.model)
         if self.kv_layout == "paged":
             sc = self.scheduler.config
             if sc.prefill_chunk and (cfg.rope == "mrope"
@@ -198,8 +236,8 @@ class ServeEngine:
             self._pools = init_paged_pools(self.model, n_blocks, bs)
             # donate the pools: the in-place block writes then update the
             # buffers directly instead of copying the whole pool per step
-            self._decode_paged = jax.jit(self.model.decode_step_paged,
-                                         donate_argnums=2)
+            self._decode_paged = jax.jit(decode_paged,
+                                         donate_argnums=(2, 6))
             self._chunk_step = jax.jit(self.model.prefill_chunk_paged,
                                        donate_argnums=2)
             # prefill padded to the cohort's block multiple, not max_seq —
@@ -218,7 +256,8 @@ class ServeEngine:
         def prefill(params, batch):
             return self.model.prefill(params, batch, max_seq)
         self._prefill = jax.jit(prefill)
-        self._decode = jax.jit(self.model.decode_step)
+        self._decode = jax.jit(decode, donate_argnums=4)
+        self._sample_tokens = jax.jit(sample_tokens)
 
     @classmethod
     def from_artifact(cls, artifact: Union[str, "os.PathLike", Any], *,
@@ -423,8 +462,9 @@ class ServeEngine:
             r.t_first_token = t_first
         cur = self._sample(logits, reqs)
         with self.spans.span("serve.read_tokens"):
+            toks = np.asarray(cur)
             for i, r in enumerate(reqs):
-                r.output.append(int(cur[i, 0]))
+                r.output.append(int(toks[i, 0]))
         self._prefills += 1
         self._prefill_tokens += len(reqs) * plen
         self._live_kv_slots += len(reqs) * self.max_seq
@@ -548,8 +588,9 @@ class ServeEngine:
             logits_u, jnp.asarray(row_to_u, jnp.int32), axis=0)
         cur = self._sample(logits, reqs)
         with self.spans.span("serve.read_tokens"):
+            toks = np.asarray(cur)
             for i, r in enumerate(reqs):
-                r.output.append(int(cur[i, 0]))
+                r.output.append(int(toks[i, 0]))
         self._prefills += 1
         self._prefill_tokens += U * plen
         group = PagedSlotGroup(reqs, table, cur, plen, allocator=alloc,
@@ -612,8 +653,9 @@ class ServeEngine:
         return new_tokens
 
     def _decode_group(self, group: SlotGroup) -> int:
-        """One decode call for ``group``, then its sampling, token reads
-        and retirement; returns the tokens it added."""
+        """One decode call for ``group`` (the model step and its sampling
+        in one program), then its token read and retirement; returns the
+        tokens it added."""
         paged = isinstance(group, PagedSlotGroup)
         span = self.spans.span
         with span("serve.decode", cohort=group.cohort, width=group.width,
@@ -631,16 +673,18 @@ class ServeEngine:
                         group.ensure_frontier()
                     table = group.device_table()
                 with span("serve.dispatch"):
-                    logits, self._pools = self._decode_paged(
+                    tokens, self._pools, self.key = self._decode_paged(
                         self.params, group.cur, self._pools, table,
-                        jnp.int32(group.pos))
+                        jnp.int32(group.pos), group.device_temps(),
+                        self.key)
                 group.pos += 1
             else:
                 with span("serve.dispatch"):
-                    logits, group.caches = self._decode(
-                        self.params, group.cur, group.caches)
+                    tokens, group.caches, self.key = self._decode(
+                        self.params, group.cur, group.caches,
+                        group.device_temps(), self.key)
             with span("serve.device_wait") as wait:
-                jax.block_until_ready(logits)
+                jax.block_until_ready(tokens)
             # the timed step: from the fault point to the device's result
             dt = wait.t1 - decode.t0
             if self.straggler is not None:
@@ -651,12 +695,13 @@ class ServeEngine:
             self._slot_steps += group.width
             self._active_slot_steps += sum(
                 1 for r in group.requests if r is not None)
-            group.cur = self._sample(logits, group.requests)
+            group.cur = tokens
             new_tokens = 0
             with span("serve.read_tokens"):
+                toks = np.asarray(tokens)
                 for i, r in enumerate(group.requests):
                     if r is not None and len(r.output) < r.max_new_tokens:
-                        r.output.append(int(group.cur[i, 0]))
+                        r.output.append(int(toks[i, 0]))
                         new_tokens += 1
             self._retire(group)
         return new_tokens
@@ -684,9 +729,10 @@ class ServeEngine:
                         r.t_first_token = t_first
                 group.cur = self._sample(logits, group.requests)
                 with self.spans.span("serve.read_tokens"):
+                    toks = np.asarray(group.cur)
                     for i, r in enumerate(group.requests):
                         if r is not None:
-                            r.output.append(int(group.cur[i, 0]))
+                            r.output.append(int(toks[i, 0]))
                 self._retire(group)
 
     def _retire(self, group: SlotGroup) -> None:
@@ -713,15 +759,14 @@ class ServeEngine:
 
     def _sample(self, logits: jax.Array,
                 rows: List[Optional[Request]]) -> jax.Array:
+        """The first token of an admission (or of a finished chunked
+        prefill): one jitted :func:`sample_tokens` call that advances
+        ``self.key`` in the same order as the decode calls do."""
         with self.spans.span("serve.sample"):
-            self.key, sub = jax.random.split(self.key)
-            greedy = jnp.argmax(logits[:, 0], axis=-1)
-            temps = jnp.asarray([r.temperature if r is not None else 0.0
-                                 for r in rows])[:, None]
-            noisy = jax.random.categorical(
-                sub, logits[:, 0] / jnp.maximum(temps, 1e-6))
-            tok = jnp.where(temps[:, 0] > 0, noisy, greedy)
-            return tok[:, None].astype(jnp.int32)
+            temps = np.asarray([r.temperature if r is not None else 0.0
+                                for r in rows], np.float32)
+            tok, self.key = self._sample_tokens(logits, temps, self.key)
+            return tok
 
     # -- stats + measurement feedback ---------------------------------------
 

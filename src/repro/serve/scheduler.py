@@ -251,10 +251,25 @@ class SlotGroup:
         self.caches = caches
         self.cur = cur
         self.plen = plen
+        self._temps: Optional[tuple] = None
+        self._dev_temps = None
 
     @property
     def width(self) -> int:
         return len(self.requests)
+
+    def device_temps(self):
+        """The rows' sampling temperatures as a device ``(width,)``
+        float32 array, 0 for pad and finished rows. Uploaded again only
+        when the rows change (admission, retirement, compaction): on a
+        TPU v5e host an upload adds 0.1-0.3 ms to a decode call, the
+        comparison a few microseconds."""
+        temps = tuple(r.temperature if r is not None else 0.0
+                      for r in self.requests)
+        if temps != self._temps:
+            self._temps = temps
+            self._dev_temps = jnp.asarray(np.asarray(temps, np.float32))
+        return self._dev_temps
 
     @property
     def active_rows(self) -> List[int]:

@@ -237,6 +237,22 @@ def test_clean_granite_zero_errors_on_all_three_passes():
     assert ja.audit_engine_donation(eng) == []
 
 
+def test_j003_flags_a_decode_step_that_donates_only_its_key():
+    """The decode program donates the pools and the PRNG key; a step that
+    donates the key alone still copies the whole pool per call, and the
+    key's aliasing must not hide that."""
+    rcfg = get_reduced_config(GRANITE)
+    params = init_params(jax.random.PRNGKey(0), rcfg)
+    eng = ServeEngine(rcfg, params, max_batch=2, max_seq=32,
+                      scheduler=SchedulerConfig(page_size=8))
+    assert ja.audit_engine_donation(eng) == []
+    fused = eng._decode_paged
+    eng._decode_paged = jax.jit(fused.__wrapped__, donate_argnums=6)
+    diags = ja.audit_engine_donation(eng)
+    assert [(d.code, d.site) for d in diags] == \
+        [("J003", "engine.decode_step_paged")]
+
+
 # ---------------------------------------------------------------------------
 # Satellite: the checker must not mutate global state
 # ---------------------------------------------------------------------------

@@ -349,3 +349,149 @@ def test_engine_admits_next_cohort_mid_decode(setup):
     assert prefill_points[1] < 7
     assert next(r for r in eng.done if r.rid == 0).output and \
         len(eng.done) == 7
+
+
+# ---------------------------------------------------------------------------
+# Sampling inside the decode programs: one device call and one token read
+# per decode call
+# ---------------------------------------------------------------------------
+
+LAYOUTS = {
+    "paged": SchedulerConfig(kv_layout="paged", page_size=8),
+    "contiguous": SchedulerConfig(kv_layout="contiguous"),
+}
+
+
+def _eager_sample(logits, temps, key):
+    """Sampling as separate eager ops on the model's logits: a key split,
+    the greedy argmax, a categorical draw at max(temperature, 1e-6), and
+    a select by temperature > 0."""
+    key, sub = jax.random.split(key)
+    greedy = jnp.argmax(logits[:, 0], axis=-1)
+    t = jnp.asarray(temps, jnp.float32)[:, None]
+    noisy = jax.random.categorical(sub, logits[:, 0] / jnp.maximum(t, 1e-6))
+    tok = jnp.where(t[:, 0] > 0, noisy, greedy)
+    return tok[:, None].astype(jnp.int32), key
+
+
+def _sample_eagerly(eng):
+    """Rewire ``eng`` to the eager path: the model's bare decode step
+    returns logits, and sampling runs after it as eager ops, at admission
+    and at every decode call, on the engine's key in the same order."""
+    step_paged = jax.jit(eng.model.decode_step_paged, donate_argnums=2)
+    step = jax.jit(eng.model.decode_step)
+
+    def paged(params, cur, pools, table, pos, temps, key):
+        logits, pools = step_paged(params, cur, pools, table, pos)
+        tok, key = _eager_sample(logits, temps, key)
+        return tok, pools, key
+
+    def contiguous(params, cur, caches, temps, key):
+        logits, caches = step(params, cur, caches)
+        tok, key = _eager_sample(logits, temps, key)
+        return tok, caches, key
+
+    def admission(logits, rows):
+        temps = [r.temperature if r is not None else 0.0 for r in rows]
+        tok, eng.key = _eager_sample(logits, temps, eng.key)
+        return tok
+
+    eng._decode_paged, eng._decode, eng._sample = paged, contiguous, \
+        admission
+    return eng
+
+
+def _sampling_mix(cfg, temps, seed=21):
+    """Two length buckets, ragged answer lengths (so groups compact)."""
+    rng = np.random.default_rng(seed)
+    reqs = []
+    for i, (plen, n) in enumerate(((8, 10), (8, 3), (8, 6), (12, 4),
+                                   (12, 8))):
+        r = _mk(rng, cfg, i, plen, n)
+        r.temperature = temps[i % len(temps)]
+        reqs.append(r)
+    return reqs
+
+
+def _outputs(eng, reqs):
+    for r in reqs:
+        eng.submit(r)
+    eng.run()
+    assert len(eng.done) == len(reqs)
+    return {r.rid: list(r.output) for r in eng.done}
+
+
+@pytest.mark.parametrize("temps", [(0.0,), (0.8, 0.0, 0.3)],
+                         ids=["greedy", "mixed"])
+@pytest.mark.parametrize("layout", sorted(LAYOUTS))
+def test_decode_program_samples_like_the_eager_path(setup, layout, temps):
+    """Sampling traced into the decode program makes, token for token,
+    what the eager ops make from the model's logits with the same key
+    sequence — greedy rows and temperature rows alike."""
+    cfg, params = setup
+    fused = ServeEngine(cfg, params, max_batch=4, max_seq=24, seed=3,
+                        scheduler=LAYOUTS[layout])
+    eager = _sample_eagerly(ServeEngine(cfg, params, max_batch=4,
+                                        max_seq=24, seed=3,
+                                        scheduler=LAYOUTS[layout]))
+    assert eager.kv_layout == fused.kv_layout == layout
+    assert _outputs(fused, _sampling_mix(cfg, temps)) == \
+        _outputs(eager, _sampling_mix(cfg, temps))
+
+
+@pytest.mark.parametrize("layout", sorted(LAYOUTS))
+def test_mixed_cohort_is_seeded_and_keeps_greedy_rows(setup, layout):
+    """Greedy and temperature-0.8 rows in one cohort: the same seed gives
+    the same outputs in two engines, and the greedy rows match a run in
+    which every row is greedy."""
+    cfg, params = setup
+    runs = [_outputs(ServeEngine(cfg, params, max_batch=4, max_seq=24,
+                                 seed=9, scheduler=LAYOUTS[layout]),
+                     _sampling_mix(cfg, temps))
+            for temps in ((0.0, 0.8), (0.0, 0.8), (0.0,))]
+    mixed, again, greedy = runs
+    assert mixed == again
+    hot = [r.rid for r in _sampling_mix(cfg, (0.0, 0.8)) if r.temperature]
+    for rid in mixed:
+        if rid not in hot:
+            assert mixed[rid] == greedy[rid]
+    # the temperature rows do draw: some token leaves the greedy path
+    assert any(mixed[rid] != greedy[rid] for rid in hot)
+
+
+@pytest.mark.parametrize("layout,chunk", [("paged", 0), ("contiguous", 0),
+                                          ("paged", 16)],
+                         ids=["paged", "contiguous", "paged-chunked"])
+def test_one_device_call_and_one_read_per_decode_call(setup, layout, chunk):
+    """``serve.sample`` counts admissions (and chunked-prefill
+    completions) only; each decode call is one jitted call, read back
+    by one ``serve.read_tokens``."""
+    import dataclasses
+    cfg, params = setup
+    eng = ServeEngine(cfg, params, max_batch=4, max_seq=40,
+                      scheduler=dataclasses.replace(LAYOUTS[layout],
+                                                    prefill_chunk=chunk))
+    calls = {"n": 0}
+    name = "_decode_paged" if layout == "paged" else "_decode"
+    program = getattr(eng, name)
+
+    def counted(*args):
+        calls["n"] += 1
+        return program(*args)
+    setattr(eng, name, counted)
+    reqs = _sampling_mix(cfg, (0.0, 0.8))
+    if chunk:
+        rng = np.random.default_rng(22)
+        reqs.append(_mk(rng, cfg, 5, 20, 4))     # 20 > 16: chunked
+    for r in reqs:
+        eng.submit(r)
+    stats = eng.run()
+    spans = stats["spans"]
+    assert stats["requests"] == len(reqs)
+    assert stats["decode_steps"] > stats["prefills"] > 1
+    assert calls["n"] == stats["decode_steps"]
+    assert spans["serve.sample"]["n"] == stats["prefills"]
+    assert spans["serve.read_tokens"]["n"] == \
+        stats["prefills"] + stats["decode_steps"]
+    if chunk:
+        assert stats["chunk_steps"] > 0

@@ -10,7 +10,9 @@ and every test of this file skips when it cannot be described. Code that
 asks ``jax.default_backend()`` still sees the CPU here, so the tests steer
 the paged decode onto its Pallas branch and compiled kernels themselves.
 """
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -24,6 +26,7 @@ from repro.kernels.moe_gmm import moe_gmm
 from repro.kernels.paged_attention import paged_attention
 from repro.models.model import Model, init_params
 from repro.models.paged_cache import RESERVED_BLOCKS, init_paged_pools
+from repro.serve.engine import sampled_decode_steps
 
 #: one TPU v5e chip's HBM (Google Cloud documentation, "TPU v5e")
 V5E_HBM_BYTES = 16 * 10**9
@@ -94,8 +97,9 @@ def test_moe_gmm_granite_experts_compiles(one_chip, no_compile_cache):
 
 
 def _qwen3_decode_step(monkeypatch, place, replicated):
-    """Lower the full-width qwen3 paged decode step (B=8, max_seq 2048)
-    on its Pallas branch; ``place(tree, pspecs)`` shards the shapes."""
+    """Lower the engine's full-width qwen3 paged decode program (B=8,
+    max_seq 2048, sampling inside) on its Pallas branch;
+    ``place(tree, pspecs)`` shards the shapes."""
     monkeypatch.setenv("REPRO_PAGED_BACKEND", "pallas")
     monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "0")
     cfg = get_config("qwen3_1_7b")
@@ -108,9 +112,29 @@ def _qwen3_decode_step(monkeypatch, place, replicated):
         lambda: init_paged_pools(model, RESERVED_BLOCKS + B * nc, bs)),
         "pools")
     s = _on(replicated)
-    step = jax.jit(model.decode_step_paged, donate_argnums=2)
+    # the engine's decode program: the model step, then its sampling
+    _, decode_paged = sampled_decode_steps(model)
+    step = jax.jit(decode_paged, donate_argnums=(2, 6))
     return step.lower(params, s((B, 1), jnp.int32), pools,
-                      s((B, nc), jnp.int32), s((), jnp.int32)).compile()
+                      s((B, nc), jnp.int32), s((), jnp.int32),
+                      s((B,), jnp.float32), s((2,), jnp.uint32)).compile()
+
+
+def _all_gathers(text):
+    """The all-gather instructions of compiled HLO ``text`` (plain,
+    ``-start`` and ``-done``, tuple results too), each as the element
+    counts of its result shapes; and how many all-gather opcodes the text
+    holds, so a form the parse misses shows as a difference."""
+    gathers = []
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT\s+)?%?[\w.\-]+ = (.+?) "
+                     r"all-gather(?:-start|-done)?\(", line)
+        if m:
+            gathers.append([
+                math.prod(int(d) for d in dims.split(",") if d)
+                for dims in re.findall(r"\w+\[([^\]]*)\]", m.group(1))])
+    n_ops = len(re.findall(r"\sall-gather(?:-start|-done)?\(", text))
+    return gathers, n_ops
 
 
 def _device_bytes(compiled):
@@ -150,5 +174,11 @@ def test_qwen3_tp4_paged_decode_step_compiles(topo, no_compile_cache,
     with jax.set_mesh(mesh):
         c = _qwen3_decode_step(monkeypatch, place, NamedSharding(mesh, P()))
     text = c.as_text()
-    assert "tpu_custom_call" in text and "all-gather" not in text
+    assert "tpu_custom_call" in text
+    # the only gathers are the sampling's argmax over vocab-sharded
+    # logits: one (max, index) pair per shard and row, 4 x 8 elements;
+    # every gather is parsed, so none of the pools can hide among them
+    gathers, n_ops = _all_gathers(text)
+    assert n_ops >= 1 and len(gathers) == n_ops
+    assert all(n <= 4 * 8 for sizes in gathers for n in sizes), gathers
     assert _device_bytes(c) < V5E_HBM_BYTES
