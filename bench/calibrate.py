@@ -7,12 +7,13 @@ control's, seed by seed, in one process.
 Each seed is a whole run of the cell (weights, warm-up, lead-in, a window
 at the cell's own load) followed by the check, with the control read at
 the same positions: the reference computed in float8 e4m3, the precision
-below the bfloat16 the configurations serve in. The limit in the
-configuration file goes above the largest program reading and below the
-smallest control reading. The control goes through the same comparison
-as the program, and has to come out as not correct: the command exits 1
-where it passes on any seed, or where the program fails. The benchmark's
-own runs never read the control.
+below the bfloat16 the configurations serve in. Every reading
+``bench/check.py`` takes is printed for both, compared or not; a limit
+in the configuration file goes above the largest program reading of its
+number and below the smallest control reading. The control goes through
+the same comparison as the program, and has to come out as not correct:
+the command exits 1 where it passes on any seed, or where the program
+fails. The benchmark's own runs never read the control.
 """
 from __future__ import annotations
 
@@ -50,9 +51,10 @@ def main(argv=None) -> int:
                            peaks, t_process=time.perf_counter(),
                            control=True)
         out = {"workload": args.workload, "seed": seed,
-               "program": line["compared"]["max_logit_gap"]["value"],
-               "control": line["control"]["max_logit_gap"],
-               "limit": line["compared"]["max_logit_gap"]["limit"],
+               "program": line["readings"],
+               "control": {k: v for k, v in line["control"].items()
+                           if k != "correct"},
+               "limits": {k: c["limit"] for k, c in line["compared"].items()},
                "correct": line["correct"],
                "control_correct": line["control"]["correct"],
                "metrics": line["metrics"],

@@ -5,10 +5,12 @@ from the seed with the longest among them, is run through the
 configuration's reference once each: prompt and served tokens, padded at
 the end to one fixed length (the causal mask keeps the padding out of
 every position that counts). At each position whose next token was
-served, the reading is the reference's largest logit minus its logit of
-the served token. The number compared is the widest such gap over the
-sample; the configuration file holds its limit and where it came from.
-Valid for greedy tokens, which is all the mixes send.
+served, the gap is the reference's largest logit minus its logit of the
+served token (0 where the served token is its first choice). From the
+gaps over the sample come the readings (``readings``): the widest gap
+and the mean gap. The configuration file's ``limits`` name the readings
+compared, each with its limit; ``limits_from`` says where it came from. Valid for
+greedy tokens, which is all the mixes send.
 """
 from __future__ import annotations
 
@@ -16,13 +18,13 @@ from typing import Any, Dict, List
 
 import numpy as np
 
-from bench import spec, weights
+from bench import weights
 
 #: sequences per reference call
 REF_BATCH = 2
 #: served tokens the sample reaches before it stops growing
-SAMPLE_TOKENS = 256
-SAMPLE_MAX_REQUESTS = 12
+SAMPLE_TOKENS = 1024
+SAMPLE_MAX_REQUESTS = 48
 
 
 def sample(finished: List[Any], seed: int) -> List[Any]:
@@ -81,36 +83,43 @@ def batched(fn, tokens: np.ndarray, *rest: np.ndarray) -> np.ndarray:
     return np.concatenate(out)
 
 
-def run(cfg_file: Dict[str, Any], max_seq: int, finished: List[Any],
-        seed: int, bench_dir=spec.BENCH_DIR, control: bool = False
-        ) -> Dict[str, Any]:
-    """Check a sample of ``finished`` (the harness's tracked requests).
+def readings(gaps: np.ndarray) -> Dict[str, float]:
+    """The numbers a configuration's ``limits`` can name, from the gaps at
+    the positions whose next token was served."""
+    return {"max_logit_gap": float(np.max(gaps)),
+            "mean_logit_gap": float(np.mean(gaps))}
+
+
+def run(cfg_file: Dict[str, Any], ref, max_seq: int, finished: List[Any],
+        seed: int, control: bool = False) -> Dict[str, Any]:
+    """Check a sample of ``finished`` (the harness's tracked requests)
+    against ``ref``, the configuration's reference module.
     ``control`` also puts the control through the same comparison: at the
-    same positions, the gap of the token that the reference computed in
-    float8 puts first, and whether that passes the limit."""
-    ref = spec.reference_module(cfg_file["reference"], bench_dir)
+    same positions, the gaps of the tokens that the reference computed in
+    float8 puts first, and whether those pass the limits."""
     m = cfg_file["model"]
     picked = sample(finished, seed)
     limits = cfg_file["limits"]
     if not picked:
         return {"correct": False, "sample_requests": 0, "sample_tokens": 0,
-                "compared": {"max_logit_gap": (float("inf"),
-                                               limits["max_logit_gap"])}}
+                "compared": {k: (float("inf"), lim)
+                             for k, lim in limits.items()}}
     length = ref_length(max_seq, ref.LOGIT_CHUNK)
     tokens, targets, mask = sequences(
         [(t.spec.prompt, t.req.output) for t in picked], length)
-    w = weights.make(m, seed)
+    w = weights.make(ref.shapes(m), m["dtype"], seed)
     gaps = batched(lambda a, b: ref.gaps(w, a, b, m), tokens, targets)
-    gap = float(np.max(gaps[mask]))
-    out = {"correct": bool(gap <= limits["max_logit_gap"]),
+    got = readings(gaps[mask])
+    compared = {k: (got[k], lim) for k, lim in limits.items()}
+    out = {"correct": all(v <= lim for v, lim in compared.values()),
            "sample_requests": len(picked), "sample_tokens": int(mask.sum()),
-           "compared": {"max_logit_gap": (gap, limits["max_logit_gap"])}}
+           "readings": got, "compared": compared}
     if control:
         firsts = batched(lambda a: ref.argmax(w, a, m, fp8=True), tokens)
         cgaps = batched(lambda a, b: ref.gaps(w, a, b, m), tokens,
                         firsts.astype(np.int32))
-        cgap = float(np.max(cgaps[mask]))
+        cgot = readings(cgaps[mask])
         # the control through the same comparison: it has to fail it
-        out["control"] = {"max_logit_gap": cgap,
-                          "correct": bool(cgap <= limits["max_logit_gap"])}
+        out["control"] = dict(cgot, correct=all(
+            cgot[k] <= lim for k, lim in limits.items()))
     return out

@@ -11,6 +11,9 @@
 ``setup_s``     process start to window start
 ``trace``       ``bench/trace.py``'s summary of the traced window, or None
 ``model``       the configuration's published sizes
+``reference``   the configuration's reference module, whose
+                ``prefill_flops``, ``decode_token_flops`` and
+                ``paged_attention_need`` count the work
 ``peaks``       the chip's entry of ``bench/peaks.json``
 """
 from __future__ import annotations
@@ -18,8 +21,6 @@ from __future__ import annotations
 from typing import Any, Dict, List, Optional
 
 import numpy as np
-
-from bench import flops
 
 
 def percentile(xs: List[float], q: float) -> Optional[float]:
@@ -112,12 +113,12 @@ def step_mfu_pct(rec: Dict[str, Any]) -> Optional[float]:
     tr = _device_trace(rec)
     if tr is None:
         return None
-    m = rec["model"]
+    m, ref = rec["model"], rec["reference"]
     work = traced_work(rec)
     if not work["prefills"] and not work["decode_keys"]:
         return None
-    total = (sum(flops.prefill_flops(m, p) for p in work["prefills"])
-             + sum(flops.decode_token_flops(m, k)
+    total = (sum(ref.prefill_flops(m, p) for p in work["prefills"])
+             + sum(ref.decode_token_flops(m, k)
                    for k in work["decode_keys"]))
     return 100.0 * total / (tr["window_s"] * rec["peaks"]["bf16_flops_per_s"])
 
@@ -141,14 +142,12 @@ def kernel_roofline_pct(rec: Dict[str, Any], kernel: str
     keys = traced_work(rec)["decode_keys"]
     if k_s <= 0 or not keys:
         return None
-    m = rec["model"]
+    m, ref = rec["model"], rec["reference"]
     need_f = need_b = 0
     for k in keys:
-        need = flops.paged_attention_need(m, k)
+        need = ref.paged_attention_need(m, k)
         need_f += need["flops"]
         need_b += need["bytes"]
-    need_f *= m["n_layers"]
-    need_b *= m["n_layers"]
     pk = rec["peaks"]
     least = max(need_f / pk["bf16_flops_per_s"], need_b / pk["hbm_bytes_per_s"])
     return 100.0 * least / k_s

@@ -24,14 +24,23 @@ call holds one layer's activations and one chunk's logits.
 ``fp8=True`` is the control: every matrix product takes its operands
 rounded to float8 e4m3 (per-tensor scale to the format's largest value),
 the precision below the bfloat16 the configurations serve in.
+
+This module is all the harness knows of the architecture, which a
+configuration file names under ``"reference"``: ``shapes`` (the weights
+it reads), ``to_program`` (the same arrays in the served params tree),
+the work the math needs (``prefill_flops``, ``decode_token_flops``,
+``paged_attention_need``) and the forward (``gaps``, ``argmax``). Another
+architecture brings a module of its own with the same names.
 """
 from __future__ import annotations
 
 import functools
-from typing import Any, Dict
+from typing import Any, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
+
+from bench import flops
 
 HIGHEST = jax.lax.Precision.HIGHEST
 EPS = 1e-6
@@ -165,3 +174,95 @@ def gaps(w, tokens, targets, m):
 def argmax(w, tokens, m, fp8=False):
     """(B, S) int32: the token each position puts first."""
     return _argmax(w, tokens, _key(m), fp8)
+
+
+# -- what the harness reads besides the forward -------------------------------
+
+def shapes(m: Dict[str, Any]) -> Dict[str, Tuple[Tuple[int, ...], int, str]]:
+    """name -> (shape, fan_in or 0 for a norm offset, dtype kind): every
+    weight the forward reads, layers stacked on the leading axis. Kind
+    ``f32`` stays float32 (the router, as the program keeps it); ``w`` is
+    drawn in the serving dtype."""
+    L, d, V = m["n_layers"], m["d_model"], m["vocab_size"]
+    hq, hkv, hd = m["n_heads"], m["n_kv_heads"], m["head_dim"]
+    out = {
+        "embed": ((V, d), d, "w"),
+        "final_norm": ((d,), 0, "w"),
+        "attn_norm": ((L, d), 0, "w"),
+        "mlp_norm": ((L, d), 0, "w"),
+        "wq": ((L, d, hq, hd), d, "w"),
+        "wk": ((L, d, hkv, hd), d, "w"),
+        "wv": ((L, d, hkv, hd), d, "w"),
+        "wo": ((L, hq, hd, d), hq * hd, "w"),
+    }
+    if m.get("qk_norm"):
+        out["q_norm"] = ((L, hd), 0, "w")
+        out["k_norm"] = ((L, hd), 0, "w")
+    if m.get("n_experts", 0):
+        E, f = m["n_experts"], m["moe_d_ff"]
+        out["router"] = ((L, d, E), d, "f32")
+        out["w_gate"] = ((L, E, d, f), d, "w")
+        out["w_up"] = ((L, E, d, f), d, "w")
+        out["w_down"] = ((L, E, f, d), f, "w")
+    else:
+        F = m["d_ff"]
+        out["w_gate"] = ((L, d, F), d, "w")
+        out["w_up"] = ((L, d, F), d, "w")
+        out["w_down"] = ((L, F, d), F, "w")
+    return out
+
+
+def to_program(w: Dict[str, Any], m: Dict[str, Any]) -> Dict[str, Any]:
+    """The same arrays, not copied, in ``repro.models.model``'s params
+    tree: one attention block in the block pattern, so every layer is
+    scanned under ``stack.pos0`` and the ``tail`` is empty."""
+    del m  # one block kind: the tree does not depend on the sizes
+    mixer = {k: w[k] for k in ("wq", "wk", "wv", "wo")}
+    if "q_norm" in w:
+        mixer["q_scale"], mixer["k_scale"] = w["q_norm"], w["k_norm"]
+    ffn = {k: w[k] for k in ("w_gate", "w_up", "w_down")}
+    if "router" in w:
+        ffn["router"] = w["router"]
+    return {
+        "embed": w["embed"],
+        "final_norm": {"scale": w["final_norm"]},
+        "stack": {"pos0": {"norm1": {"scale": w["attn_norm"]},
+                           "norm2": {"scale": w["mlp_norm"]},
+                           "mixer": mixer, "ffn": ffn}},
+        "tail": {},
+    }
+
+
+def _linear_per_token(m: Dict[str, Any]) -> int:
+    """Matrix-multiply FLOPs of one token through one layer, outside the
+    attention core: the projections, and the MLP or the router and the
+    ``top_k`` experts the token is routed to."""
+    d, hq, hkv, hd = m["d_model"], m["n_heads"], m["n_kv_heads"], m["head_dim"]
+    attn = 2 * d * hq * hd + 2 * 2 * d * hkv * hd + 2 * hq * hd * d
+    if m.get("n_experts", 0):
+        ffn = 2 * d * m["n_experts"] + m["top_k"] * 2 * 3 * d * m["moe_d_ff"]
+    else:
+        ffn = 2 * 3 * d * m["d_ff"]
+    return attn + ffn
+
+
+def decode_token_flops(m: Dict[str, Any], keys: int) -> int:
+    """One decoded token whose query attends ``keys`` positions in every
+    layer."""
+    per_layer = _linear_per_token(m) + flops.attention_core_flops(m, keys)
+    return m["n_layers"] * per_layer + flops.unembed_flops(m)
+
+
+def prefill_flops(m: Dict[str, Any], plen: int) -> int:
+    """One causal prompt of ``plen`` tokens; logits at its last position."""
+    return (m["n_layers"] * (plen * _linear_per_token(m)
+                             + flops.attention_core_flops(
+                                 m, flops.causal_keys(plen)))
+            + flops.unembed_flops(m))
+
+
+def paged_attention_need(m: Dict[str, Any], keys: int) -> Dict[str, int]:
+    """FLOPs and HBM bytes one query row needs from the paged decode
+    kernel over every layer, each attending ``keys`` positions."""
+    need = flops.attention_row_need(m, keys)
+    return {k: m["n_layers"] * v for k, v in need.items()}

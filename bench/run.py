@@ -70,8 +70,10 @@ def model_config(cfg_file):
     """The program's ModelConfig for a configuration file, checked against
     the sizes the file states."""
     from repro.configs import get_config
+    # JSON has no tuples: a list (such as a block pattern) becomes one
     cfg = get_config(cfg_file["repro_config"]).with_overrides(
-        **cfg_file.get("overrides", {}))
+        **{k: tuple(v) if isinstance(v, list) else v
+           for k, v in cfg_file.get("overrides", {}).items()})
     for key, want in cfg_file["model"].items():
         if getattr(cfg, key) != want:
             raise spec.SpecError(f"{cfg_file['repro_config']}: {key} is "
@@ -90,18 +92,20 @@ def make_request(i, prompt, n):
     return Request(rid=i, prompt=prompt, max_new_tokens=n)
 
 
-def build(cfg_file, mix, seed: int):
+def build(cfg_file, ref, mix, seed: int):
     """The served engine for one configuration and mix, warmed up: weights
-    from the seed, every program shape the mix can make run once."""
+    from the seed, in the shapes and the tree that ``ref`` (the
+    configuration's reference module) gives, and every program shape the
+    mix can make run once."""
     import jax
     from repro.serve.engine import ServeEngine
     from repro.serve.scheduler import SchedulerConfig
     serve = dict(cfg_file["serve"], max_seq=max_seq(
         mix, cfg_file["serve"]["page_size"]))
     m = cfg_file["model"]
-    w = weights.make(m, seed)
+    w = weights.make(ref.shapes(m), m["dtype"], seed)
     jax.block_until_ready(w)
-    engine = ServeEngine(model_config(cfg_file), weights.to_program(w),
+    engine = ServeEngine(model_config(cfg_file), ref.to_program(w, m),
                          max_batch=serve["max_batch"],
                          max_seq=serve["max_seq"],
                          seed=weights.jax_seed(seed, "engine"),
@@ -119,8 +123,9 @@ def execute(workload: str, seed: int, seconds: float, trace: bool,
             keep_trace: str = None, t_process: float = T_PROCESS,
             control: bool = False):
     """Everything after the device check; returns the result line.
-    ``control`` adds the control's reading under ``control`` (for
-    ``bench/calibrate.py``; the benchmark's own runs never read it)."""
+    ``control`` adds the control's readings under ``control`` and the
+    program's, compared or not, under ``readings`` (for
+    ``bench/calibrate.py``; the benchmark's own runs never read them)."""
     import jax
 
     bench_dir = root / "bench"
@@ -129,8 +134,9 @@ def execute(workload: str, seed: int, seconds: float, trace: bool,
     cfg_file = spec.config(cell["config"], bench_dir)
     mix = spec.traffic(cell["traffic"], bench_dir)
     m = cfg_file["model"]
+    ref = spec.reference_module(cfg_file["reference"], bench_dir)
     compiles = CompileCounter()
-    engine, serve, n_cohorts, warm_steps = build(cfg_file, mix, seed)
+    engine, serve, n_cohorts, warm_steps = build(cfg_file, ref, mix, seed)
     warm = compiles.snapshot()
     log(f"set-up: {n_cohorts} warm-up cohorts in {warm_steps} steps, "
         f"{warm['lowered']} programs lowered, {warm['compiled']} compiled, "
@@ -188,6 +194,7 @@ def execute(workload: str, seed: int, seconds: float, trace: bool,
         "setup_s": win["w0"] - t_process,
         "trace": None,
         "model": m,
+        "reference": ref,
         "peaks": peaks,
     }
     device = {"platform": devices[0].platform,
@@ -222,15 +229,14 @@ def execute(workload: str, seed: int, seconds: float, trace: bool,
     del driver, engine, stream
     gc.collect()
     t_check = time.perf_counter()
-    result = check.run(cfg_file, serve["max_seq"], finished, seed, bench_dir,
+    result = check.run(cfg_file, ref, serve["max_seq"], finished, seed,
                        control=control)
     log(f"check: {result['sample_requests']} requests, "
         f"{result['sample_tokens']} served tokens, against the reference "
         f"in {time.perf_counter() - t_check:.3f} s")
     if control:
-        log(f"check: control max_logit_gap "
-            f"{result['control']['max_logit_gap']!r}, correct "
-            f"{result['control']['correct']}")
+        log(f"check: program readings {result['readings']}")
+        log(f"check: control readings {result['control']}")
     for name, (value, limit) in result["compared"].items():
         log(f"check: {name} {value!r} limit {limit!r}")
 
@@ -240,6 +246,7 @@ def execute(workload: str, seed: int, seconds: float, trace: bool,
     if breakdown is not None:
         line["breakdown"] = breakdown
     if control:
+        line["readings"] = result["readings"]
         line["control"] = result["control"]
     line["compared"] = {k: {"value": v, "limit": lim}
                         for k, (v, lim) in result["compared"].items()}

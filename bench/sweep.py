@@ -134,7 +134,8 @@ def main(argv=None) -> int:
     cell = spec.workload(args.workload)
     cfg_file = spec.config(cell["config"])
     mix = spec.traffic(cell["traffic"])
-    engine, _, _, _ = run.build(cfg_file, mix, args.seeds[0])
+    ref = spec.reference_module(cfg_file["reference"])
+    engine, _, _, _ = run.build(cfg_file, ref, mix, args.seeds[0])
     sweep(engine, cfg_file, mix, args.seeds, args.rates, args.seconds,
           out=lambda s: print(s, flush=True))
     return 0

@@ -6,10 +6,11 @@ import statistics
 import numpy as np
 import pytest
 
-from bench import flops, records, spec, traffic, warmup
+from bench import records, spec, traffic, warmup
 
 QWEN3 = spec.config("qwen3_1_7b")["model"]
 GRANITE = spec.config("granite_moe_1b_a400m")["model"]
+DECODER = spec.reference_module("decoder")
 
 
 # -- traffic ------------------------------------------------------------------
@@ -121,12 +122,12 @@ def test_qwen3_flops_by_hand():
     # MLP 3 * 2*2048*6144; head 2*2048*151936
     per_layer = 8388608 + 2 * 4194304 + 8388608 + 75497472
     head = 622329856
-    assert flops.decode_token_flops(QWEN3, 1) == \
+    assert DECODER.decode_token_flops(QWEN3, 1) == \
         28 * (per_layer + 4 * 16 * 128) + head
-    assert flops.decode_token_flops(QWEN3, 1000) - \
-        flops.decode_token_flops(QWEN3, 1) == 28 * 4 * 16 * 128 * 999
+    assert DECODER.decode_token_flops(QWEN3, 1000) - \
+        DECODER.decode_token_flops(QWEN3, 1) == 28 * 4 * 16 * 128 * 999
     # causal prompt of 3: 3 tokens through the layers, 1+2+3 keys, 1 head
-    assert flops.prefill_flops(QWEN3, 3) == \
+    assert DECODER.prefill_flops(QWEN3, 3) == \
         28 * (3 * per_layer + 6 * 4 * 16 * 128) + head
 
 
@@ -134,27 +135,50 @@ def test_granite_counts_active_experts_only():
     # per layer: q 2*1024*1024, k and v 2*1024*512 each, o 2*1024*1024,
     # router 2*1024*32, 8 experts of 3 * 2*1024*512; head 2*1024*49155
     per_layer = 2097152 + 2 * 1048576 + 2097152 + 65536 + 8 * 3145728
-    assert flops.decode_token_flops(GRANITE, 1) == \
+    assert DECODER.decode_token_flops(GRANITE, 1) == \
         24 * (per_layer + 4 * 16 * 64) + 100669440
 
 
 def test_paged_attention_need_by_hand():
-    # 100 keys of K and V over 8 heads x 128, plus q and out (16 x 128)
-    need = flops.paged_attention_need(QWEN3, 100)
-    assert need["bytes"] == 2 * (2 * 100 * 8 * 128) + 2 * (2 * 16 * 128)
-    assert need["flops"] == 4 * 16 * 128 * 100
+    # in each of 28 layers: 100 keys of K and V over 8 heads x 128, plus
+    # q and out (16 x 128)
+    need = DECODER.paged_attention_need(QWEN3, 100)
+    assert need["bytes"] == 28 * (2 * (2 * 100 * 8 * 128)
+                                  + 2 * (2 * 16 * 128))
+    assert need["flops"] == 28 * 4 * 16 * 128 * 100
+
+
+#: (prefill of 1024 and of 2048 tokens, a decoded token over 1100 keys,
+#: the paged kernel's need for a row over 1100 keys), as counted before
+#: the counts moved into the reference modules
+PINNED_COUNTS = {
+    "qwen3_1_7b": (3007216877568, 6254329593856, 3693215744,
+                   {"flops": 28 * 9011200, "bytes": 28 * 4513792}),
+    "granite_moe_1b_a400m": (826395334656, 1755769214976, 965351424,
+                             {"flops": 24 * 4505600, "bytes": 24 * 2256896}),
+}
+
+
+@pytest.mark.parametrize("name", sorted(PINNED_COUNTS))
+def test_work_counts_are_the_same_as_before(name):
+    cfg_file = spec.config(name)
+    ref, m = spec.reference_module(cfg_file["reference"]), cfg_file["model"]
+    assert (ref.prefill_flops(m, 1024), ref.prefill_flops(m, 2048),
+            ref.decode_token_flops(m, 1100),
+            ref.paged_attention_need(m, 1100)) == PINNED_COUNTS[name]
 
 
 def test_roofline_share_from_trace_summary():
     rec = _rec()
     rec["model"], rec["peaks"] = QWEN3, {"bf16_flops_per_s": 197e12,
                                          "hbm_bytes_per_s": 819e9}
+    rec["reference"] = DECODER
     # tokens 1 and 2 of request 2 are decodes at keys 201 and 202
     rec["trace"] = {"chips": 1, "window_s": 1.0, "busy_s": 0.25,
                     "host_window": (10.55, 11.2),
                     "kernels": {"k": {"s": 1e-3, "n": 56}}}
-    need = sum(flops.paged_attention_need(QWEN3, k)["bytes"]
-               for k in (201, 202)) * 28
+    need = sum(DECODER.paged_attention_need(QWEN3, k)["bytes"]
+               for k in (201, 202))
     assert records.kernel_roofline_pct(rec, "k") == pytest.approx(
         100 * need / 819e9 / 1e-3)
     assert spec.metric_fn("device_idle.chat")(rec) == pytest.approx(75.0)
@@ -242,6 +266,15 @@ def test_benchmark_json_names_only_files_that_exist():
             spec.metric_fn(m["name"])
 
 
+def test_every_cell_reports_every_end_to_end_metric():
+    b = spec.benchmark()
+    names = {m["name"] for m in b["end_to_end"]}
+    assert {"itl_p95_s", "tokens_per_s", "setup_s"} <= names
+    for w in b["workloads"]:
+        got = {m["name"] for m in spec.metrics_for(b, w["name"], False)}
+        assert got == names, w["name"]
+
+
 def test_sets_report_spreads_by_the_standard_library_quartiles():
     from bench import sets
     a = [1.0, 1.1, 0.9, 1.0, 1.05, 0.95]
@@ -253,3 +286,10 @@ def test_sets_report_spreads_by_the_standard_library_quartiles():
     # the run farthest from the median is left out of the trimmed spread
     assert got[1]["spread_trimmed"] == 0.0
     assert got[2]["widest_spread"] == max(got[0]["spread"], got[1]["spread"])
+
+
+def test_check_readings_by_hand():
+    from bench import check
+    got = check.readings(np.array([0.0, 0.0, 0.5, 0.1], np.float32))
+    assert got == pytest.approx({"max_logit_gap": 0.5,
+                                 "mean_logit_gap": 0.15})

@@ -39,7 +39,8 @@ def test_a_short_sweep_of_a_tiny_cell(tmp_path):
     cfg_file = spec.config("tiny_dense", bench_dir)
     mix = dict(spec.traffic("tinychat", bench_dir), load=0.8,
                slo={"ttft_s": 30.0, "tpot_s": 30.0, "share": 0.9})
-    engine, _, _, _ = run.build(cfg_file, mix, 7)
+    ref = spec.reference_module(cfg_file["reference"], bench_dir)
+    engine, _, _, _ = run.build(cfg_file, ref, mix, 7)
     lines = []
     pooled, decision = sweep.sweep(engine, cfg_file, mix, [1, 2], [5.0, 10.0],
                                    0.5, out=lines.append)

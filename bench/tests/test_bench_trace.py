@@ -92,3 +92,45 @@ def test_breakdown_is_bounded_and_named(recorded):
     # the host-clock window comes from the paired spans themselves
     a, b = s["host_window"]
     assert b - a == pytest.approx(s["window_s"], rel=0.05)
+
+
+#: step_mfu.chat and paged_attention_roofline.chat of each recorded window
+#: with the requests of ``_requests``, as counted before the work counts
+#: moved into the reference modules: the same digits after the move
+PINNED_SHARES = {
+    "qwen3_chat": (3.696865699145934, 15.304651791289743),
+    "qwen3_chat_spans": (4.545842857474632, 16.080840348642916),
+}
+
+
+def _requests(a, b):
+    """Six requests of the chat mix's prompt lengths whose tokens are
+    spread over the host window [a, b]: one prefill each, the rest
+    decodes."""
+    reqs = []
+    for i, plen in enumerate((128, 256, 512, 1024, 128, 256)):
+        n = 4 + i
+        reqs.append({"due": a, "plen": plen, "max_new": n,
+                     "tokens": [a + (b - a) * (j + 0.5 + 0.1 * i) / (n + 1)
+                                for j in range(n)]})
+    return reqs
+
+
+@pytest.mark.parametrize("stem", sorted(PINNED_SHARES))
+def test_model_and_kernel_shares_of_the_recorded_windows(stem):
+    from jax.profiler import ProfileData
+    from bench import spec
+    spans = json.loads((DATA / f"{stem}.xplane.pb.spans.json").read_text())
+    pd = ProfileData.from_serialized_xspace(gzip.decompress(
+        (DATA / f"{stem}.xplane.pb.gz").read_bytes()))
+    s = trace.summarize(pd, spans)
+    a, b = s["host_window"]
+    cfg_file = spec.config("qwen3_1_7b")
+    rec = {"window": {"start": a, "w0": a, "w1": b},
+           "requests": _requests(a, b), "spans": spans, "stats": {},
+           "trace": s, "model": cfg_file["model"],
+           "reference": spec.reference_module(cfg_file["reference"]),
+           "peaks": spec.peaks("TPU v5 lite")}
+    got = (spec.metric_fn("step_mfu.chat")(rec),
+           spec.metric_fn("paged_attention_roofline.chat")(rec))
+    assert got == PINNED_SHARES[stem]
